@@ -42,11 +42,9 @@ def run() -> None:
     flat = vol.reshape(-1)
     us = time_fn(q, flat)
     emit("codec/quantize1d/rate12_32", us, f"{raw/us*1e6/1e9:.2f}GB/s")
-    # pallas kernel (interpret mode: correctness vehicle, not speed)
-    from repro.kernels.zfp import kernel
-
-    xb = ref.blockify(vol, 3)
-    enc = lambda: kernel.encode_pallas(xb, planes=12, ndim=3)
+    # pallas kernel (interpret mode on a CPU: correctness vehicle, not
+    # speed)
+    enc = lambda: ops.compress(vol, planes=12, ndim=3, backend="pallas")
     us = time_fn(lambda: jax.block_until_ready(enc()))
     emit("codec/pallas_encode3d_interpret/rate12_32", us,
          "interpret-mode (semantics only)")

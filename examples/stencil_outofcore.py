@@ -21,6 +21,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import place_compile_cache
 from repro.core.executor import AsyncExecutor
 from repro.core.outofcore import OOCConfig, OutOfCoreWave, \
     paper_code_fields
@@ -144,6 +145,7 @@ def checkpoint_demo(ckpt_dir: str, resume: bool) -> None:
 
 
 def main() -> None:
+    place_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--checkpoint-dir", default=None,
                     help="snapshot the run here after STEPS/2 steps "

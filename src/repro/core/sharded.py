@@ -112,8 +112,10 @@ class ShardedExecutor:
 
         ``devices``/``mesh`` pin shards to JAX devices (e.g. the
         emulated CPU devices of ``--xla_force_host_platform_device_
-        count``); with neither, all shards share the default device —
-        still the same graphs, transfers, and results. ``cache_bytes``
+        count``); with neither, shards go round-robin onto
+        ``jax.local_devices()``, so a four-chip host runs one shard per
+        chip and a one-device host shares its device — the same graphs,
+        transfers, and results either way. ``cache_bytes``
         is the *per-device* residency budget. ``monitor`` defaults to
         a fresh ``HeartbeatMonitor(nshards)``.
         """
@@ -121,6 +123,8 @@ class ShardedExecutor:
         self.schedule = get_schedule(schedule)
         self.temporal = self.schedule.temporal
         self.plan = cfg.temporal_plan(self.temporal)
+        if devices is None and mesh is None:
+            devices = jax.local_devices()
         self.specs: List[ShardSpec] = partition_domain(
             cfg.ndiv, nshards, devices=devices, mesh=mesh,
         )
@@ -384,7 +388,8 @@ class ShardedExecutor:
     ) -> "ShardedExecutor":
         """Rebuild every shard from ``<directory>/shard<dd>/`` and
         resume bit-identically. Device pins are process state: pass
-        ``devices``/``mesh`` to re-pin on the current topology (the
+        ``devices``/``mesh`` to re-pin on the current topology, or
+        neither for round-robin over ``jax.local_devices()`` (the
         shard *layout* comes from the manifests)."""
         root = pathlib.Path(directory)
         subdirs = sorted(
@@ -397,10 +402,9 @@ class ShardedExecutor:
             )
         if mesh is not None:
             devices = list(mesh.devices.flat)
-        pins = (
-            [devices[d % len(devices)] for d in range(len(subdirs))]
-            if devices else [None] * len(subdirs)
-        )
+        elif devices is None:
+            devices = jax.local_devices()
+        pins = [devices[d % len(devices)] for d in range(len(subdirs))]
         shards = [
             AsyncExecutor.restore(
                 str(p), schedule=schedule, cache_bytes=cache_bytes,

@@ -5,6 +5,7 @@
   cdecode  fused ZFP-decode + flash-decode attention (compressed KV)
   sscan    VMEM-resident Mamba-1 selective scan
 
-All validated in interpret mode against their pure-jnp oracles
-(this container is CPU-only; TPU v5e is the lowering target).
+All validated in interpret mode against their pure-jnp oracles on the
+CPU; on a TPU they run as compiled Mosaic (``platform.dispatch``
+decides, from the platform a program is lowered for).
 """

@@ -28,24 +28,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import dispatch
 from repro.kernels.zfp import ref as zref
 from repro.models.kvcache import CHUNK
 
 
-def _decode_tile(payload, emax, inv_perm, planes: int, head_dim: int):
+def _decode_tile(payload, emax, planes: int, head_dim: int):
     """(nbc, W) uint32 payload -> (CHUNK, D) f32 tile, in-registers."""
-    u = zref.unpack_planes(payload, planes, 2, jnp.float32,
-                           inv_perm=inv_perm)
-    c = zref.from_negabinary(u)
-    q = zref.inv_transform(c, 2)
-    x = zref.from_fixedpoint(q, emax, jnp.float32)  # (nbc, 16)
+    x = zref.decode_blocks(payload.T, emax, planes, 2, jnp.float32).T
     sb, db = CHUNK // 4, head_dim // 4
     x = x.reshape(sb, db, 4, 4).transpose(0, 2, 1, 3)
     return x.reshape(CHUNK, head_dim)
 
 
 def _kernel(
-    pk_ref, ek_ref, pv_ref, ev_ref, q_ref, len_ref, inv_ref,
+    pk_ref, ek_ref, pv_ref, ev_ref, q_ref, len_ref,
     m_ref, l_ref, acc_ref, *, planes: int, head_dim: int,
 ):
     ci = pl.program_id(1)
@@ -56,11 +53,10 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    inv_perm = inv_ref[...][0]
-    k_tile = _decode_tile(pk_ref[...][0], ek_ref[...][0], inv_perm,
-                          planes, head_dim)
-    v_tile = _decode_tile(pv_ref[...][0], ev_ref[...][0], inv_perm,
-                          planes, head_dim)
+    k_tile = _decode_tile(pk_ref[...][0], ek_ref[...][0], planes,
+                          head_dim)
+    v_tile = _decode_tile(pv_ref[...][0], ev_ref[...][0], planes,
+                          head_dim)
     q = q_ref[...][0]  # (QPK, D), already scaled by 1/sqrt(D)
     logits = jnp.einsum(
         "qd,td->qt", q, k_tile, preferred_element_type=jnp.float32
@@ -85,7 +81,7 @@ def _kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("planes", "head_dim", "qpk", "interpret"),
+    static_argnames=("planes", "head_dim", "qpk"),
 )
 def fused_cdecode_attention(
     payload_k: jax.Array,  # (BG, NB, W) uint32
@@ -98,21 +94,17 @@ def fused_cdecode_attention(
     planes: int,
     head_dim: int,
     qpk: int,
-    interpret: bool = True,
 ):
     """Returns flash-decoding partials (m, l, acc) over the compressed
     history; the caller merges the raw tail window."""
     bg, nb, w = payload_k.shape
     nbc = (CHUNK // 4) * (head_dim // 4)
     nchunks = nb // nbc
-    _, inv, _ = zref.level_order(planes, 2, 32)
-    inv_arr = jnp.asarray([inv], jnp.int32)
     grid = (bg, nchunks)
     pay_spec = pl.BlockSpec((1, nbc, w), lambda b, c: (b, c, 0))
     em_spec = pl.BlockSpec((1, nbc), lambda b, c: (b, c))
     q_spec = pl.BlockSpec((1, qpk, head_dim), lambda b, c: (b, 0, 0))
     len_spec = pl.BlockSpec((1, 1), lambda b, c: (0, 0))
-    inv_spec = pl.BlockSpec((1, 16), lambda b, c: (0, 0))
     out_specs = [
         pl.BlockSpec((1, qpk), lambda b, c: (b, 0)),
         pl.BlockSpec((1, qpk), lambda b, c: (b, 0)),
@@ -123,12 +115,16 @@ def fused_cdecode_attention(
         jax.ShapeDtypeStruct((bg, qpk), jnp.float32),
         jax.ShapeDtypeStruct((bg, qpk, head_dim), jnp.float32),
     ]
-    return pl.pallas_call(
+    call = functools.partial(
+        pl.pallas_call,
         functools.partial(_kernel, planes=planes, head_dim=head_dim),
         grid=grid,
         in_specs=[pay_spec, em_spec, pay_spec, em_spec, q_spec,
-                  len_spec, inv_spec],
+                  len_spec],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
-    )(payload_k, emax_k, payload_v, emax_v, q_scaled, hist_len, inv_arr)
+    )
+    return dispatch(
+        lambda *args, interpret: call(interpret=interpret)(*args),
+        payload_k, emax_k, payload_v, emax_v, q_scaled, hist_len,
+    )

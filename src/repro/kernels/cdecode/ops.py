@@ -13,7 +13,7 @@ from repro.models.kvcache import CHUNK, CompressedKV
 
 
 @functools.partial(
-    jax.jit, static_argnames=("planes", "max_len", "interpret")
+    jax.jit, static_argnames=("planes", "max_len")
 )
 def fused_compressed_decode_attention(
     q: jax.Array,  # (B, 1, H, D)
@@ -21,7 +21,6 @@ def fused_compressed_decode_attention(
     *,
     planes: int,
     max_len: int,
-    interpret: bool = True,
 ) -> jax.Array:
     b, _, h, d = q.shape
     kvh = ckv.tail_k.shape[2]
@@ -38,7 +37,7 @@ def fused_compressed_decode_attention(
     m_h, l_h, acc_h = kernel.fused_cdecode_attention(
         pk, ek, pv, ev, qr,
         jnp.full((1, 1), hist_len, jnp.int32),
-        planes=planes, head_dim=d, qpk=qpk, interpret=interpret,
+        planes=planes, head_dim=d, qpk=qpk,
     )
     # raw tail window partials
     tail_pos = ckv.length - hist_len

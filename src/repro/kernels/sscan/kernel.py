@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import dispatch
+
 
 def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, h_ref,
             *, chunk: int):
@@ -60,9 +62,7 @@ def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, h_ref,
     h_ref[...] = h_chunk[-1:][None][0]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("chunk", "d_tile", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("chunk", "d_tile"))
 def selective_scan_pallas(
     dt: jax.Array,  # (B, S, D) f32
     a: jax.Array,  # (D, N) f32
@@ -73,7 +73,6 @@ def selective_scan_pallas(
     *,
     chunk: int = 64,
     d_tile: int = 256,
-    interpret: bool = True,
 ):
     """Returns (y (B,S,D), h_last (B,D,N))."""
     bsz, s, d = x.shape
@@ -86,7 +85,8 @@ def selective_scan_pallas(
         a=pl.BlockSpec((d_tile, n), lambda b, di, ci: (di, 0)),
         h=pl.BlockSpec((1, d_tile, n), lambda b, di, ci: (b, di, 0)),
     )
-    return pl.pallas_call(
+    call = functools.partial(
+        pl.pallas_call,
         functools.partial(_kernel, chunk=chunk),
         grid=grid,
         in_specs=[
@@ -98,5 +98,8 @@ def selective_scan_pallas(
             jax.ShapeDtypeStruct((bsz, s, d), jnp.float32),
             jax.ShapeDtypeStruct((bsz, d, n), jnp.float32),
         ],
-        interpret=interpret,
-    )(dt, x, b_in, c_in, a, h0)
+    )
+    return dispatch(
+        lambda *args, interpret: call(interpret=interpret)(*args),
+        dt, x, b_in, c_in, a, h0,
+    )

@@ -1,7 +1,8 @@
 """jit'd wrappers for the acoustic stencil kernel.
 
-``backend="ref"`` is the XLA-compiled oracle (fast on CPU, ground
-truth); ``backend="pallas"`` the TPU kernel (interpret mode here).
+``backend="ref"`` is the XLA-compiled oracle (ground truth);
+``backend="pallas"`` the Pallas kernel: compiled Mosaic on a TPU,
+interpret mode on any other platform (``repro.kernels.platform``).
 """
 
 from __future__ import annotations
@@ -10,33 +11,27 @@ import functools
 from typing import Literal, Tuple
 
 import jax
-import jax.numpy as jnp
 
 from . import kernel, ref
 
 Backend = Literal["ref", "pallas"]
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
+@functools.partial(jax.jit, static_argnames=("backend",))
 def wave_step(
     p_prev: jax.Array,
     p_cur: jax.Array,
     vel2: jax.Array,
     *,
     backend: Backend = "ref",
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """One step on padded fields -> (p_next interior, lap interior)."""
     if backend == "pallas":
-        return kernel.wave_step_pallas(
-            p_prev, p_cur, vel2, interpret=interpret
-        )
+        return kernel.wave_step_pallas(p_prev, p_cur, vel2)
     return ref.wave_step(p_prev, p_cur, vel2)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("steps", "backend", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("steps", "backend"))
 def temporal_steps(
     p_prev: jax.Array,
     p_cur: jax.Array,
@@ -44,7 +39,6 @@ def temporal_steps(
     *,
     steps: int,
     backend: Backend = "ref",
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """``steps`` fixed-shape time steps on same-shape fields.
 
@@ -61,8 +55,7 @@ def temporal_steps(
     def body(carry, _):
         pp, pc = carry
         pn, _ = wave_step(
-            ref.pad_bc(pp), ref.pad_bc(pc), vel2,
-            backend=backend, interpret=interpret,
+            ref.pad_bc(pp), ref.pad_bc(pc), vel2, backend=backend,
         )
         return (pc, pn), None
 
@@ -76,9 +69,17 @@ def temporal_steps(
     return pp, pc
 
 
-@functools.partial(
-    jax.jit, static_argnames=("steps", "backend", "interpret")
-)
+def fused_path(backend: Backend, steps: int) -> str:
+    """Which stencil path ``fused_temporal_steps`` takes: a name that a
+    run can print next to its results."""
+    if backend == "pallas":
+        launches = -(-steps // kernel.MAX_RUNGS)
+        return (f"wave_multistep_pallas: {steps} steps in {launches} "
+                f"launch(es) of <= {kernel.MAX_RUNGS} fused rungs")
+    return f"ref.wave_step ladder: {steps} XLA steps (lax.scan)"
+
+
+@functools.partial(jax.jit, static_argnames=("steps", "backend"))
 def fused_temporal_steps(
     p_prev: jax.Array,
     p_cur: jax.Array,
@@ -86,32 +87,18 @@ def fused_temporal_steps(
     *,
     steps: int,
     backend: Backend = "ref",
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Temporal-k entry point: ``steps`` fused time steps, dispatched
-    on the step count and backend.
+    """Temporal-k entry point: ``steps`` time steps with zero BC.
 
-    On a compiled Pallas backend with more than one step (and a y
-    extent the fused tile width ``steps * HALO`` divides), this runs
-    ``kernel.wave_multistep_pallas`` — one kernel launch that keeps
-    every intermediate rung in VMEM. Everywhere else (ref backend,
-    interpret-mode/CPU pallas, steps == 1, or an indivisible y) it
-    falls back to ``steps`` sequential single-step calls via
-    ``temporal_steps``. Both paths compute the identical per-element
-    expression tree, so the dispatch never changes results — the
-    fused kernel is bit-identical to the ladder in f32
-    (tests/test_temporal.py pins this).
+    The Pallas backend runs ``kernel.wave_multistep_pallas``, which
+    tiles any shape and keeps up to ``kernel.MAX_RUNGS`` intermediate
+    rungs per launch in VMEM; the ref backend runs the ``temporal_steps``
+    ladder. Both compute the per-element expression tree of
+    ``ref.ladder_steps`` (tests/test_temporal.py pins this);
+    ``fused_path`` names the path taken.
     """
-    if (
-        backend == "pallas"
-        and not interpret
-        and steps > 1
-        and p_cur.shape[1] % (steps * ref.HALO) == 0
-    ):
+    if backend == "pallas":
         return kernel.wave_multistep_pallas(
-            p_prev, p_cur, vel2, steps=steps, interpret=interpret
+            p_prev, p_cur, vel2, steps=steps
         )
-    return temporal_steps(
-        p_prev, p_cur, vel2, steps=steps, backend=backend,
-        interpret=interpret,
-    )
+    return temporal_steps(p_prev, p_cur, vel2, steps=steps, backend=backend)

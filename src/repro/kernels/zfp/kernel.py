@@ -4,28 +4,21 @@ TPU adaptation notes (vs cuZFP's CUDA implementation):
 
 * cuZFP assigns one warp per 4^d block and uses warp shuffles /
   ``__ballot_sync`` for the bit-plane transpose. TPUs have no warp
-  semantics; instead each grid step encodes a *tile* of ``TB`` blocks
-  held in VMEM and performs every stage (exponent extraction, fixed-point
-  conversion, lifting, negabinary, plane packing) as wide VPU ops over
-  the ``(TB, 4^d)`` tile. The bit-plane transpose becomes a masked
-  shift-accumulate, which is dense and branch-free.
-
-* The kernels consume *block-major* layout ``(nb, 4^d)``. The out-of-core
-  engine keeps streamed datasets in this layout on the host so the codec
-  hot path contains no in-kernel transposes (Mosaic-friendly); layout
-  conversion (``ref.blockify``) happens once per block transfer as a
-  cheap XLA reshape outside the kernel.
-
-* Exponents are extracted with IEEE-754 bit manipulation rather than
-  ``frexp`` (no libm in Mosaic). With the ``_EMAX_FLOOR`` clamp this is
-  bit-identical to the oracle, including zero/denormal blocks.
-
+  semantics. The codec is coefficient-major instead (``ref.blockify``):
+  a kernel tile is ``(4^d, TR, 128)``, row ``i`` holding coefficient
+  ``i`` of 128*TR blocks, one block per (sublane, lane) slot. Every
+  codec stage is then a whole-vreg operation on coefficient slabs:
+  exponent extraction, fixed-point conversion, the lifting transform
+  (which combines statically known slabs), negabinary, and the bit-
+  plane transpose, a static shift-and-or of slabs.
+* The level-order permutation and the bit positions depend only on
+  ``(planes, ndim)``: static slices, no gather and no table inputs.
+* So the kernel bodies ARE ``ref.encode_blocks`` / ``decode_blocks``,
+  run on a VMEM tile: bit-identical to the oracle by construction, and
+  every stage is integer arithmetic or an exact power-of-two scaling.
 * cuZFP's per-bit-plane group testing (the sequential part the paper
   § IV complains about in cuSZ) is dropped: in fixed-rate mode,
   truncation at a fixed plane is equivalent and branch-free.
-
-Validated against ``ref.py`` in interpret mode (this container is
-CPU-only); see tests/test_zfp_kernel.py for the shape/dtype/rate sweep.
 """
 
 from __future__ import annotations
@@ -34,128 +27,101 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import dispatch
 
 from . import ref
 
-# Tile size: blocks encoded per grid step. VMEM footprint at TB=256,
-# ndim=3, planes<=32: in 64 KiB + bits intermediate <=2 MiB + out 32 KiB.
-DEFAULT_TILE_BLOCKS = 256
+LANES = 128
+# Blocks per grid step at most: TR = 8 sublane rows of 128 lanes, one
+# vreg per coefficient slab. VMEM per step: 64 slabs in + <= 64 words
+# out, 256 KiB each way.
+DEFAULT_TILE_BLOCKS = 8 * LANES
 
 
-def _emax_tile(x: jax.Array) -> jax.Array:
-    """Per-block max frexp-style exponent via IEEE-754 bits. x: (TB, N) f32."""
-    bits = lax.bitcast_convert_type(x, jnp.int32)
-    raw = (bits >> 23) & 0xFF
-    e = jnp.where(raw == 0, jnp.int32(-126), raw - 126)
-    # zeros/denormals both map to -126 which is below the -90 floor, so
-    # the clamp makes this agree exactly with ref._exponent + floor.
-    return jnp.maximum(jnp.max(e, axis=-1), jnp.int32(-90))
-
-
-def _encode_kernel(
-    x_ref, masks_ref, perm_ref, payload_ref, emax_ref,
-    *, planes: int, ndim: int,
-):
-    x = x_ref[...]
-    emax = _emax_tile(x)
-    scale = lax.bitcast_convert_type((26 - emax + 127) << 23, jnp.float32)
-    q = jnp.rint(x * scale[:, None]).astype(jnp.int32)
-    c = ref.fwd_transform(q, ndim)
-    u = ref.truncate_planes(
-        ref.to_negabinary(c), planes, ndim, masks=masks_ref[...][0]
+def _encode_kernel(x_ref, payload_ref, emax_ref, *, planes: int,
+                   ndim: int):
+    payload_ref[...], emax_ref[...] = ref.encode_blocks(
+        x_ref[...], planes, ndim
     )
-    payload_ref[...] = ref.pack_planes(u, planes, ndim, perm=perm_ref[...][0])
-    emax_ref[...] = emax[:, None]
 
 
-def _decode_kernel(
-    payload_ref, emax_ref, inv_perm_ref, x_ref, *, planes: int, ndim: int
-):
-    u = ref.unpack_planes(
-        payload_ref[...], planes, ndim, jnp.float32,
-        inv_perm=inv_perm_ref[...][0],
+def _decode_kernel(payload_ref, emax_ref, x_ref, *, planes: int,
+                   ndim: int):
+    x_ref[...] = ref.decode_blocks(
+        payload_ref[...], emax_ref[...], planes, ndim, jnp.float32
     )
-    c = ref.from_negabinary(u)
-    q = ref.inv_transform(c, ndim)
-    emax = emax_ref[...][:, 0]
-    scale = lax.bitcast_convert_type((emax - 26 + 127) << 23, jnp.float32)
-    x_ref[...] = q.astype(jnp.float32) * scale[:, None]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("planes", "ndim", "tile_blocks", "interpret")
-)
-def encode_pallas(
-    xb: jax.Array,
-    *,
-    planes: int,
-    ndim: int,
-    tile_blocks: int = DEFAULT_TILE_BLOCKS,
-    interpret: bool = True,
-):
-    """xb: (nb, 4^ndim) f32, nb divisible by tile_blocks.
-    Returns (payload (nb, W) uint32, emax (nb, 1) int32)."""
-    nb, n = xb.shape
-    assert n == ref.block_size(ndim)
-    assert nb % tile_blocks == 0, (nb, tile_blocks)
+def _tile_rows(rows: int) -> int:
+    return min(rows, DEFAULT_TILE_BLOCKS // LANES)
+
+
+def _encode_call(xs, *, planes: int, ndim: int, interpret: bool):
+    n, rows, _ = xs.shape
+    tr = _tile_rows(rows)
     nwords = ref.payload_words(ndim, planes)
-    grid = (nb // tile_blocks,)
-    # static tables passed as inputs (Pallas kernels may not capture
-    # constant arrays); replicated to every grid step.
-    masks = jnp.asarray([ref.plane_masks(planes, ndim, 32)], jnp.uint32)
-    perm, _, _ = ref.level_order(planes, ndim, 32)
-    perm = jnp.asarray([perm], jnp.int32)
     return pl.pallas_call(
         functools.partial(_encode_kernel, planes=planes, ndim=ndim),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_blocks, n), lambda i: (i, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
+        grid=(rows // tr,),
+        in_specs=[pl.BlockSpec((n, tr, LANES), lambda i: (0, i, 0))],
         out_specs=[
-            pl.BlockSpec((tile_blocks, nwords), lambda i: (i, 0)),
-            pl.BlockSpec((tile_blocks, 1), lambda i: (i, 0)),
+            pl.BlockSpec((nwords, tr, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((tr, LANES), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, nwords), jnp.uint32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nwords, rows, LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
         interpret=interpret,
-    )(xb, masks, perm)
+    )(xs)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("planes", "ndim", "tile_blocks", "interpret")
-)
-def decode_pallas(
-    payload: jax.Array,
-    emax: jax.Array,
-    *,
-    planes: int,
-    ndim: int,
-    tile_blocks: int = DEFAULT_TILE_BLOCKS,
-    interpret: bool = True,
-):
-    """Inverse of encode_pallas. Returns (nb, 4^ndim) f32."""
-    nb, nwords = payload.shape
-    assert nwords == ref.payload_words(ndim, planes)
-    assert nb % tile_blocks == 0, (nb, tile_blocks)
+def _decode_call(payload, emax, *, planes: int, ndim: int,
+                 interpret: bool):
+    nwords, rows, _ = payload.shape
+    tr = _tile_rows(rows)
     n = ref.block_size(ndim)
-    grid = (nb // tile_blocks,)
-    _, inv, _ = ref.level_order(planes, ndim, 32)
-    inv = jnp.asarray([inv], jnp.int32)
     return pl.pallas_call(
         functools.partial(_decode_kernel, planes=planes, ndim=ndim),
-        grid=grid,
+        grid=(rows // tr,),
         in_specs=[
-            pl.BlockSpec((tile_blocks, nwords), lambda i: (i, 0)),
-            pl.BlockSpec((tile_blocks, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
+            pl.BlockSpec((nwords, tr, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((tr, LANES), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_blocks, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, n), jnp.float32),
+        out_specs=pl.BlockSpec((n, tr, LANES), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, rows, LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
         interpret=interpret,
-    )(payload, emax, inv)
+    )(payload, emax)
+
+
+@functools.partial(jax.jit, static_argnames=("planes", "ndim"))
+def encode_pallas(xs: jax.Array, *, planes: int, ndim: int):
+    """xs: coefficient-major (4^ndim, rows, 128) f32; block b is at
+    ``[:, b // 128, b % 128]``. ``rows`` is at most 8 or a multiple of
+    8. Returns (payload (W, rows, 128) uint32, emax (rows, 128) int32),
+    the same layout."""
+    n, rows, lanes = xs.shape
+    assert n == ref.block_size(ndim) and lanes == LANES, xs.shape
+    assert rows % _tile_rows(rows) == 0, rows
+    call = functools.partial(_encode_call, planes=planes, ndim=ndim)
+    return tuple(dispatch(call, xs))
+
+
+@functools.partial(jax.jit, static_argnames=("planes", "ndim"))
+def decode_pallas(payload: jax.Array, emax: jax.Array, *, planes: int,
+                  ndim: int):
+    """Inverse of ``encode_pallas``: returns (4^ndim, rows, 128) f32."""
+    nwords, rows, lanes = payload.shape
+    assert nwords == ref.payload_words(ndim, planes) and lanes == LANES
+    assert emax.shape == (rows, LANES), emax.shape
+    call = functools.partial(_decode_call, planes=planes, ndim=ndim)
+    return dispatch(call, payload, emax)

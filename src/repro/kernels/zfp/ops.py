@@ -1,9 +1,10 @@
 """jit'd public wrappers around the ZFP-style codec.
 
-``backend="ref"`` runs the pure-jnp oracle (XLA-compiled; fastest on this
-CPU-only container and the numerics ground truth). ``backend="pallas"``
-runs the Pallas TPU kernel — in interpret mode here, compiled Mosaic on
-real TPUs. Both produce bit-identical results (tests/test_zfp_kernel.py).
+``backend="ref"`` runs the pure-jnp oracle (XLA-compiled, the numerics
+ground truth). ``backend="pallas"`` runs the Pallas kernel: compiled
+Mosaic on a TPU, interpret mode on any other platform
+(``repro.kernels.platform``). Both produce bit-identical results
+(tests/test_zfp_kernel.py).
 """
 
 from __future__ import annotations
@@ -13,6 +14,7 @@ from typing import List, Literal, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from . import kernel, ref
 from .ref import Compressed
@@ -20,75 +22,120 @@ from .ref import Compressed
 Backend = Literal["ref", "pallas"]
 
 
-def _pad_blocks(xb: jax.Array, tile: int) -> jax.Array:
-    nb = xb.shape[0]
-    pad = (-nb) % tile
-    if pad:
-        xb = jnp.pad(xb, ((0, pad), (0, 0)))
-    return xb
-
-
 def bucket_tile(nb: int) -> int:
-    """Pallas tile size for an ``nb``-block batch: the next power of
-    two, capped at ``DEFAULT_TILE_BLOCKS``.
+    """Pallas tile size (blocks per grid step) for an ``nb``-block
+    batch: 128 blocks (one lane row) times the next power of two rows,
+    capped at ``DEFAULT_TILE_BLOCKS``.
 
     Bucketing bounds codec recompilation: the kernel compiles per
-    (tile, planes, ndim), so with ``tile = min(DEFAULT_TILE_BLOCKS,
-    nb)`` every distinct unit block-count (R vs C units, edge blocks)
-    triggered a fresh Mosaic build. Rounding the pad-to-tile size up to
-    a power of two gives at most ``log2(DEFAULT_TILE_BLOCKS)+1``
-    distinct tiles, so differently-sized units share compiled kernels
-    at the cost of <2x padding waste on the last tile."""
-    tile = 1
+    (padded block count, planes, ndim), so small units of every size
+    share the four tiles 128..1024, and larger ones pad to a multiple
+    of ``DEFAULT_TILE_BLOCKS`` — at the cost of <2x padding waste on
+    the last tile."""
+    tile = kernel.LANES
     while tile < nb and tile < kernel.DEFAULT_TILE_BLOCKS:
         tile <<= 1
     return tile
 
 
-@functools.partial(
-    jax.jit, static_argnames=("planes", "ndim", "backend", "interpret")
-)
+def _to_tiles(xs: jax.Array) -> jax.Array:
+    """Coefficient-major (N, nb) -> kernel tiles (N, rows, 128), zero-
+    padded to a whole number of ``bucket_tile`` tiles."""
+    n, nb = xs.shape
+    tile = bucket_tile(nb)
+    nbp = -(-nb // tile) * tile
+    xs = jnp.pad(xs, ((0, 0), (0, nbp - nb)))
+    return xs.reshape(n, nbp // kernel.LANES, kernel.LANES)
+
+
+def _from_tiles(t: jax.Array, nb: int) -> jax.Array:
+    """Inverse of ``_to_tiles``: (N, rows, 128) -> (N, nb)."""
+    return t.reshape(t.shape[0], -1)[:, :nb]
+
+
+# Blocks per step of the XLA codec on large units. Its bit-plane
+# intermediates take ~3 KiB a block (one uint32 per payload bit), so an
+# unchunked encode of a 128 x 1152 x 1152 unit needs more than a v5e's
+# 16 GB of HBM; 2^16 blocks a step keep them near 200 MiB.
+_REF_CHUNK = 1 << 16
+
+
+def _ref_chunked(fn, *arrays):
+    """``fn`` over coefficient-major arrays (minor axis = blocks), in
+    ``_REF_CHUNK``-block steps of a ``fori_loop``. Every codec stage is
+    per block, so the result is the unchunked one."""
+    nb = arrays[0].shape[-1]
+    if nb <= _REF_CHUNK:
+        return fn(*arrays)
+    nch = -(-nb // _REF_CHUNK)
+    arrays = [
+        jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, nch * _REF_CHUNK - nb)])
+        for a in arrays
+    ]
+    take = lambda a, i: lax.dynamic_slice_in_dim(
+        a, i * _REF_CHUNK, _REF_CHUNK, axis=a.ndim - 1
+    )
+    outs = jax.eval_shape(fn, *(take(a, 0) for a in arrays))
+    outs = [
+        jnp.zeros(o.shape[:-1] + (nch * _REF_CHUNK,), o.dtype)
+        for o in jax.tree_util.tree_leaves(outs)
+    ]
+
+    def body(i, acc):
+        got = jax.tree_util.tree_leaves(fn(*(take(a, i) for a in arrays)))
+        return [
+            lax.dynamic_update_slice_in_dim(
+                o, g, i * _REF_CHUNK, axis=o.ndim - 1
+            )
+            for o, g in zip(acc, got)
+        ]
+
+    outs = [o[..., :nb] for o in lax.fori_loop(0, nch, body, outs)]
+    return outs if len(outs) > 1 else outs[0]
+
+
+@functools.partial(jax.jit, static_argnames=("planes", "ndim", "backend"))
 def compress(
     x: jax.Array,
     *,
     planes: int,
     ndim: int = 3,
     backend: Backend = "ref",
-    interpret: bool = True,
 ) -> Compressed:
     """Fixed-rate compress the trailing ``ndim`` axes of ``x``."""
     xb = ref.blockify(x, ndim)
-    nb = xb.shape[0]
+    nb = xb.shape[1]
     if backend == "pallas" and x.dtype == jnp.float32:
-        tile = bucket_tile(nb)
-        xbp = _pad_blocks(xb, tile)
         payload, emax = kernel.encode_pallas(
-            xbp, planes=planes, ndim=ndim, tile_blocks=tile,
-            interpret=interpret,
+            _to_tiles(xb), planes=planes, ndim=ndim
         )
-        payload, emax = payload[:nb], emax[:nb, 0]
+        payload, emax = _from_tiles(payload, nb), _from_tiles(emax[None], nb)[0]
     else:
-        payload, emax = ref.encode_blocks(xb, planes, ndim)
+        payload, emax = _ref_chunked(
+            lambda a: ref.encode_blocks(a, planes, ndim), xb
+        )
     return Compressed(payload, emax, tuple(x.shape), planes, ndim, str(x.dtype))
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "interpret"))
-def decompress(
-    c: Compressed, *, backend: Backend = "ref", interpret: bool = True
-) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("backend",))
+def decompress(c: Compressed, *, backend: Backend = "ref") -> jax.Array:
     dtype = jnp.dtype(c.dtype)
     if backend == "pallas" and dtype == jnp.float32:
-        nb = c.payload.shape[0]
-        tile = bucket_tile(nb)
-        pad = (-nb) % tile
-        payload = jnp.pad(c.payload, ((0, pad), (0, 0)))
-        emax = jnp.pad(c.emax, (0, pad))[:, None]
-        xb = kernel.decode_pallas(
-            payload, emax, planes=c.planes, ndim=c.ndim_spatial,
-            tile_blocks=tile, interpret=interpret,
-        )[:nb]
+        nb = c.emax.shape[0]
+        xb = _from_tiles(
+            kernel.decode_pallas(
+                _to_tiles(c.payload), _to_tiles(c.emax[None])[0],
+                planes=c.planes, ndim=c.ndim_spatial,
+            ),
+            nb,
+        )
     else:
-        xb = ref.decode_blocks(c.payload, c.emax, c.planes, c.ndim_spatial, dtype)
+        xb = _ref_chunked(
+            lambda p, e: ref.decode_blocks(
+                p, e, c.planes, c.ndim_spatial, dtype
+            ),
+            c.payload, c.emax,
+        )
     return ref.unblockify(xb, c.shape, c.ndim_spatial)
 
 
@@ -98,7 +145,6 @@ def compress_units(
     planes: Union[int, Sequence[Optional[int]]],
     ndim: int = 3,
     backend: Backend = "ref",
-    interpret: bool = True,
 ) -> List[Union[Compressed, jax.Array]]:
     """Batched encode: dispatch every unit's encoder before blocking on
     any payload.
@@ -124,9 +170,7 @@ def compress_units(
                 f"{len(xs)} units"
             )
     return [
-        x if p is None else compress(
-            x, planes=p, ndim=ndim, backend=backend, interpret=interpret
-        )
+        x if p is None else compress(x, planes=p, ndim=ndim, backend=backend)
         for x, p in zip(xs, per_unit)
     ]
 
@@ -135,7 +179,6 @@ def decompress_units(
     cs: Sequence[Compressed],
     *,
     backend: Backend = "ref",
-    interpret: bool = True,
 ) -> List[jax.Array]:
     """Batched decode: dispatch every unit's decoder before blocking on
     any output — the counterpart of ``compress_units``.
@@ -148,9 +191,7 @@ def decompress_units(
     round-trip each). The executor's per-visit decode uses it too, for
     a single shared code path.
     """
-    return [
-        decompress(c, backend=backend, interpret=interpret) for c in cs
-    ]
+    return [decompress(c, backend=backend) for c in cs]
 
 
 @functools.partial(jax.jit, static_argnames=("planes", "ndim"))

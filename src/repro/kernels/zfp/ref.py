@@ -174,29 +174,35 @@ def bits_per_value(ndim: int, planes: int, width: int = 32) -> float:
 
 
 def _exponent(x: jax.Array) -> jax.Array:
-    """frexp-style exponent: |x| < 2^e for x != 0. Zeros get a sentinel."""
-    _, e = jnp.frexp(x)
-    return jnp.where(x == 0, jnp.int32(-(2**14)), e.astype(jnp.int32))
+    """frexp-style exponent (|x| < 2^e) from the IEEE-754 bits. Zeros
+    and denormals get the smallest normal exponent, below
+    ``_EMAX_FLOOR``, so a block of them clamps to the floor. Bit
+    arithmetic (no libm) lowers the same way in XLA and in Mosaic."""
+    dt = jnp.dtype(x.dtype)
+    bits = lax.bitcast_convert_type(x, _ITYPE[dt])
+    raw = (bits >> _MANT_BITS[dt]) & (2 * _EXP_BIAS[dt] + 1)
+    return (raw - (_EXP_BIAS[dt] - 1)).astype(jnp.int32)
 
 
 def block_emax(xb: jax.Array) -> jax.Array:
-    """Max exponent per block. xb: (nb, N) float -> (nb,) int32."""
+    """Max exponent per block. xb: coefficient-major (N, ...) float ->
+    (...) int32."""
     dt = jnp.dtype(xb.dtype)
-    e = jnp.max(_exponent(xb), axis=-1)
+    e = jnp.max(_exponent(xb), axis=0)
     return jnp.maximum(e, _EMAX_FLOOR[dt])
 
 
 def to_fixedpoint(xb: jax.Array, emax: jax.Array) -> jax.Array:
     dt = jnp.dtype(xb.dtype)
     shift = (_FRAC[dt] - emax).astype(jnp.int32)
-    scaled = xb * exp2i(shift, dt)[..., None]
+    scaled = xb * exp2i(shift, dt)[None]
     return jnp.rint(scaled).astype(_ITYPE[dt])
 
 
 def from_fixedpoint(q: jax.Array, emax: jax.Array, dtype) -> jax.Array:
     dt = jnp.dtype(dtype)
     shift = (emax - _FRAC[dt]).astype(jnp.int32)
-    return q.astype(dt) * exp2i(shift, dt)[..., None]
+    return q.astype(dt) * exp2i(shift, dt)[None]
 
 
 # ---------------------------------------------------------------------------
@@ -214,33 +220,33 @@ def _s_inv(s: jax.Array, d: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return u, u - d
 
 
-def _lift4_fwd(q: jax.Array) -> jax.Array:
-    """Two-level Haar lift along the last axis (size 4)."""
-    q0, q1, q2, q3 = q[..., 0], q[..., 1], q[..., 2], q[..., 3]
+def _lift4_fwd(q0, q1, q2, q3):
+    """Two-level Haar lift of four coefficient slabs."""
     s0, d0 = _s_fwd(q0, q1)
     s1, d1 = _s_fwd(q2, q3)
     ss, ds = _s_fwd(s0, s1)
-    return jnp.stack([ss, ds, d0, d1], axis=-1)
+    return ss, ds, d0, d1
 
 
-def _lift4_inv(c: jax.Array) -> jax.Array:
-    ss, ds, d0, d1 = c[..., 0], c[..., 1], c[..., 2], c[..., 3]
+def _lift4_inv(ss, ds, d0, d1):
     s0, s1 = _s_inv(ss, ds)
     q0, q1 = _s_inv(s0, d0)
     q2, q3 = _s_inv(s1, d1)
-    return jnp.stack([q0, q1, q2, q3], axis=-1)
+    return q0, q1, q2, q3
 
 
 def _apply_per_axis(q: jax.Array, ndim: int, fn, reverse: bool) -> jax.Array:
-    """Apply a size-4 last-axis transform along each of the trailing
-    ``ndim`` axes of q reshaped to (nb, 4, ..., 4). The inverse must
+    """Apply a size-4 lift along each in-block axis of a coefficient-
+    major ``q`` (N, ...): the coefficient axis splits into ``ndim`` axes
+    of 4, the first spatial axis the most significant. The inverse must
     visit axes in the opposite order to undo the forward exactly."""
-    nb = q.shape[0]
-    q = q.reshape((nb,) + (4,) * ndim)
-    axes = range(1, ndim + 1)
+    n, rest = q.shape[0], q.shape[1:]
+    q = q.reshape((4,) * ndim + rest)
+    axes = range(ndim)
     for ax in (reversed(axes) if reverse else axes):
-        q = jnp.moveaxis(fn(jnp.moveaxis(q, ax, -1)), -1, ax)
-    return q.reshape(nb, block_size(ndim))
+        parts = [lax.index_in_dim(q, j, ax, keepdims=False) for j in range(4)]
+        q = jnp.stack(fn(*parts), axis=ax)
+    return q.reshape((n,) + rest)
 
 
 def fwd_transform(q: jax.Array, ndim: int) -> jax.Array:
@@ -286,18 +292,15 @@ def plane_masks(planes: int, ndim: int, width: int) -> Tuple[int, ...]:
     )
 
 
-def truncate_planes(
-    u: jax.Array, planes: int, ndim: int, masks: jax.Array | None = None
-) -> jax.Array:
-    """Keep the subband-allocated top planes of each coefficient.
-    ``masks`` may be passed as an array (Pallas kernels do)."""
+def truncate_planes(u: jax.Array, planes: int, ndim: int) -> jax.Array:
+    """Keep the subband-allocated top planes of each coefficient of a
+    coefficient-major ``u`` (N, ...)."""
     w = 32 if u.dtype == jnp.uint32 else 64
-    if masks is None:
-        pv = subband_planes(int(planes), ndim, w)
-        if all(p >= w for p in pv):
-            return u
-        masks = jnp.array(plane_masks(planes, ndim, w), dtype=u.dtype)
-    return u & masks[None, :]
+    pv = subband_planes(int(planes), ndim, w)
+    if all(p >= w for p in pv):
+        return u
+    masks = jnp.array(plane_masks(planes, ndim, w), dtype=u.dtype)
+    return u & masks.reshape((-1,) + (1,) * (u.ndim - 1))
 
 
 # ---------------------------------------------------------------------------
@@ -305,73 +308,63 @@ def truncate_planes(
 # ---------------------------------------------------------------------------
 
 
-def pack_planes(
-    u: jax.Array, planes: int, ndim: int, perm: jax.Array | None = None
-) -> jax.Array:
-    """u: (nb, N) uintW, subband-truncated. Returns (nb, W) uint32
-    payload words: plane-major over the level-sorted coefficient order
-    (the ZFP stream layout with static subband allocation).
+def _rows(a: jax.Array, order) -> jax.Array:
+    """Rows of ``a`` in a static order: static slices, no gather (the
+    Pallas kernels run this code too)."""
+    return jnp.concatenate([a[i : i + 1] for i in order], axis=0)
 
-    ``perm`` may be passed as an array (the Pallas kernel does, to avoid
-    capturing constants); defaults to the static level order."""
-    nb, n = u.shape
+
+def pack_planes(u: jax.Array, planes: int, ndim: int) -> jax.Array:
+    """u: coefficient-major (N, ...) uintW. Returns (W, ...) uint32
+    payload words: plane-major over the level-sorted coefficient order
+    (the ZFP stream layout with static subband allocation). Plane j
+    carries the first ``counts[j]`` coefficients of that order, which
+    are exactly the bits ``truncate_planes`` keeps, so ``u`` need not
+    be truncated first."""
+    rest = u.shape[1:]
     w = 32 if u.dtype == jnp.uint32 else 64
-    sperm, _, counts = level_order(int(planes), ndim, w)
-    if perm is None:
-        perm = jnp.asarray(sperm, dtype=jnp.int32)
-    up = jnp.take(u, perm, axis=1)
+    perm, _, counts = level_order(int(planes), ndim, w)
+    nwords = payload_words(ndim, planes, w)
+    if not nwords:
+        return jnp.zeros((0,) + rest, jnp.uint32)
+    up = _rows(u, perm)
     segs = [
-        ((up[:, :k] >> (w - 1 - j)) & 1).astype(jnp.uint32)
+        ((up[:k] >> (w - 1 - j)) & 1).astype(jnp.uint32)
         for j, k in enumerate(counts)
     ]
-    flat = (
-        jnp.concatenate(segs, axis=1)
-        if segs
-        else jnp.zeros((nb, 0), jnp.uint32)
-    )
-    nbits = flat.shape[1]
-    nwords = payload_words(ndim, planes, w)
-    pad = nwords * WORD_BITS - nbits
+    pad = nwords * WORD_BITS - sum(counts)
     if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    lanes = jnp.arange(WORD_BITS, dtype=jnp.uint32)
-    return jnp.sum(
-        flat.reshape(nb, nwords, WORD_BITS) << lanes[None, None, :],
-        axis=-1,
-        dtype=jnp.uint32,
-    )
+        segs.append(jnp.zeros((pad,) + rest, jnp.uint32))
+    flat = jnp.concatenate(segs, axis=0).reshape((nwords, WORD_BITS) + rest)
+    words = flat[:, 0]
+    for b in range(1, WORD_BITS):
+        words = words | (flat[:, b] << b)
+    return words
 
 
 def unpack_planes(
-    words: jax.Array,
-    planes: int,
-    ndim: int,
-    dtype,
-    inv_perm: jax.Array | None = None,
+    words: jax.Array, planes: int, ndim: int, dtype
 ) -> jax.Array:
-    """Inverse of pack_planes. Returns (nb, N) uintW (low planes zero)."""
+    """Inverse of pack_planes: (W, ...) uint32 -> coefficient-major
+    (N, ...) uintW (low planes zero)."""
     dt = jnp.dtype(dtype)
     ut, w = _UTYPE[dt], _WIDTH[dt]
-    nb = words.shape[0]
+    rest = words.shape[1:]
     n = block_size(ndim)
-    _, sinv, counts = level_order(int(planes), ndim, w)
-    if inv_perm is None:
-        inv_perm = jnp.asarray(sinv, dtype=jnp.int32)
-    lanes = jnp.arange(WORD_BITS, dtype=jnp.uint32)
-    bits = ((words[:, :, None] >> lanes[None, None, :]) & 1).reshape(nb, -1)
+    _, inv, counts = level_order(int(planes), ndim, w)
+    up = jnp.zeros((n,) + rest, dtype=ut)
+    if not counts:
+        return up
+    bits = jnp.stack([(words >> b) & 1 for b in range(WORD_BITS)], axis=1)
+    bits = bits.reshape((-1,) + rest)
     pos = 0
-    planecols = []
     for j, k in enumerate(counts):
-        seg = bits[:, pos : pos + k].astype(ut)
+        seg = bits[pos : pos + k].astype(ut) << (w - 1 - j)
         pos += k
         if k < n:
-            seg = jnp.pad(seg, ((0, 0), (0, n - k)))
-        planecols.append(seg << (w - 1 - j))
-    if planecols:
-        up = functools.reduce(lambda a, b: a | b, planecols)
-    else:
-        up = jnp.zeros((nb, n), dtype=ut)
-    return jnp.take(up, inv_perm, axis=1)
+            seg = jnp.concatenate([seg, jnp.zeros((n - k,) + rest, ut)])
+        up = up | seg
+    return _rows(up, inv)
 
 
 # ---------------------------------------------------------------------------
@@ -382,12 +375,11 @@ def unpack_planes(
 def encode_blocks(
     xb: jax.Array, planes: int, ndim: int
 ) -> Tuple[jax.Array, jax.Array]:
-    """xb: (nb, 4^ndim) float32/float64 -> (payload (nb, W) uint32,
-    emax (nb,) int32)."""
+    """xb: coefficient-major (4^ndim, ...) float32/float64 -> (payload
+    (W, ...) uint32, emax (...) int32)."""
     emax = block_emax(xb)
     q = to_fixedpoint(xb, emax)
-    c = fwd_transform(q, ndim)
-    u = truncate_planes(to_negabinary(c), planes, ndim)
+    u = to_negabinary(fwd_transform(q, ndim))
     return pack_planes(u, planes, ndim), emax
 
 
@@ -422,10 +414,18 @@ def _padded_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
 
 
 def blockify(x: jax.Array, ndim: int) -> jax.Array:
-    """x: (..., s1..s_ndim) -> (nb, 4^ndim) with edge padding to x4.
+    """x: (..., s1..s_ndim) -> coefficient-major (4^ndim, nb), edge-
+    padded to x4.
 
-    Leading axes are treated as batch; trailing ``ndim`` axes are the
-    spatial axes that 4^ndim blocks tile.
+    Leading axes are batch; the trailing ``ndim`` axes are the spatial
+    axes that 4^ndim blocks tile. Blocks are numbered row-major over
+    (batch..., block indices...); coefficient ``i`` of a block is its
+    in-block offset in base 4, the first spatial axis most significant.
+
+    The layout change runs in two stages so that no intermediate has a
+    minor dimension of 4, which a TPU would pad to 128 lanes (32x its
+    size in HBM): first the in-block offsets of every spatial axis but
+    the last move to the front, then those of the last (minor) axis.
     """
     spatial = x.shape[-ndim:]
     padded = _padded_shape(spatial)
@@ -435,34 +435,38 @@ def blockify(x: jax.Array, ndim: int) -> jax.Array:
     if any(p != (0, 0) for p in pads):
         x = jnp.pad(x, pads, mode="edge")
     batch = x.shape[: x.ndim - ndim]
-    # split each spatial axis into (blocks, 4)
-    new = sum(((p // 4, 4) for p in padded), start=tuple(batch))
-    x = x.reshape(new)
-    nb_axes = x.ndim - 2 * ndim  # batch axes count
-    order = (
-        tuple(range(nb_axes))
-        + tuple(nb_axes + 2 * i for i in range(ndim))
-        + tuple(nb_axes + 2 * i + 1 for i in range(ndim))
+    nbat, k = len(batch), ndim - 1
+    nblocks = [p // 4 for p in padded]
+    x = x.reshape(
+        batch + sum(((n, 4) for n in nblocks[:-1]), start=()) + padded[-1:]
     )
-    x = x.transpose(order)
-    return x.reshape(-1, block_size(ndim))
+    digits = [nbat + 2 * i + 1 for i in range(k)]
+    blocks = [nbat + 2 * i for i in range(k)]
+    x = x.transpose(digits + list(range(nbat)) + blocks + [x.ndim - 1])
+    x = x.reshape(x.shape[:-1] + (nblocks[-1], 4))
+    x = x.transpose(
+        list(range(k)) + [x.ndim - 1] + list(range(k, x.ndim - 1))
+    )
+    return x.reshape(block_size(ndim), -1)
 
 
 def unblockify(
     xb: jax.Array, shape: Tuple[int, ...], ndim: int
 ) -> jax.Array:
-    """Inverse of blockify back to ``shape`` (crops the x4 padding)."""
+    """Inverse of blockify back to ``shape`` (crops the x4 padding),
+    in the same two stages reversed."""
     spatial = shape[-ndim:]
     padded = _padded_shape(spatial)
-    batch = shape[: len(shape) - ndim]
+    batch = tuple(shape[: len(shape) - ndim])
+    nbat, k = len(batch), ndim - 1
     nblocks = [p // 4 for p in padded]
-    x = xb.reshape(tuple(batch) + tuple(nblocks) + (4,) * ndim)
-    nb_axes = len(batch)
-    order = list(range(nb_axes))
-    for i in range(ndim):
-        order += [nb_axes + i, nb_axes + ndim + i]
-    x = x.transpose(order)
-    x = x.reshape(tuple(batch) + tuple(padded))
+    x = xb.reshape((4,) * ndim + batch + tuple(nblocks))
+    x = x.transpose(list(range(k)) + list(range(ndim, x.ndim)) + [k])
+    x = x.reshape(x.shape[:-2] + padded[-1:])
+    order = [k + i for i in range(nbat)]
+    for i in range(k):
+        order += [k + nbat + i, i]
+    x = x.transpose(order + [x.ndim - 1]).reshape(batch + padded)
     slices = tuple(slice(None) for _ in batch) + tuple(
         slice(0, s) for s in spatial
     )
@@ -479,7 +483,7 @@ def unblockify(
 class Compressed:
     """A fixed-rate compressed array (payload + per-block exponents)."""
 
-    payload: jax.Array  # (nb, W) uint32
+    payload: jax.Array  # (W, nb) uint32, word-major
     emax: jax.Array  # (nb,) int32
     shape: Tuple[int, ...]
     planes: int

@@ -84,6 +84,9 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.ooc:
+        from repro.compile_cache import place_compile_cache
+
+        place_compile_cache()
         run_ooc(args)
         return
 

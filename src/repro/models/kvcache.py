@@ -68,7 +68,7 @@ def _encode_chunk(x: jax.Array, planes: int):
     xt = jnp.moveaxis(x, 2, 1).astype(jnp.float32)  # (B, KVH, CHUNK, D)
     comp = zfp_ops.compress(xt, planes=planes, ndim=2)
     nbc = _nb_per_chunk(d)
-    payload = comp.payload.reshape(b, kvh, nbc, -1)
+    payload = comp.payload.T.reshape(b, kvh, nbc, -1)
     emax = comp.emax.reshape(b, kvh, nbc)
     return payload, emax
 
@@ -78,7 +78,7 @@ def _decode_all(payload, emax, planes: int, seq: int, head_dim: int,
     """payload: (B, KVH, NB, W) -> (B, seq, KVH, D)."""
     b, kvh, nb, w = payload.shape
     c = zfp_ref.Compressed(
-        payload.reshape(-1, w),
+        payload.reshape(-1, w).T,
         emax.reshape(-1),
         (b * kvh, seq, head_dim),
         planes,

@@ -26,11 +26,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_mesh, current_rules
 
-try:  # jax>=0.6 exports shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 
 def route(x_tokens: jax.Array, router_w: jax.Array, k: int):
     """Top-k routing. x: (T, d) -> (top_w (T,k) f32, top_i (T,k) i32,
@@ -131,7 +126,7 @@ def moe_ffn(
         fn = functools.partial(
             _expert_shard, k=k, capacity=capacity, axis=axis
         )
-        y = shard_map(
+        y = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(

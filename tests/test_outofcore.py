@@ -132,3 +132,28 @@ def test_writeback_units_once_per_sweep():
     # read-only field is never written back
     assert not [t for t in eng.transfers if t.direction == "d2h" and
                 t.field == "vel2"]
+
+
+@pytest.mark.parametrize("code", [1, 4])
+def test_pallas_backend_engine_matches_ref_backend(code):
+    """The live engine on the Pallas kernels (fused stencil, codec)
+    matches the XLA backend and the in-core reference: within float32
+    tightness with no compression (the two programs may contract
+    multiply-adds differently), within the codec's error at code 4."""
+    from repro.core.executor import AsyncExecutor
+
+    shape = (48, 16, 16)
+    p_prev, p_cur, vel2 = _initial(shape)
+    out = {}
+    for backend in ("ref", "pallas"):
+        cfg = OOCConfig(shape, 2, BT, paper_code_fields(code),
+                        backend=backend)
+        eng = AsyncExecutor(cfg, p_prev, p_cur, vel2, schedule="depth2")
+        eng.run(2 * BT)
+        out[backend] = eng.gather("p_cur")
+    _, ref_pc = _incore(p_prev, p_cur, vel2, 2 * BT)
+    scale = np.abs(ref_pc).max()
+    tol = 1e-5 if code == 1 else 0.1
+    for got in out.values():
+        assert np.abs(got - ref_pc).max() <= tol * scale
+    assert np.abs(out["pallas"] - out["ref"]).max() <= tol * scale

@@ -1,11 +1,15 @@
 """25-point stencil Pallas kernel vs jnp oracle."""
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels.stencil import kernel, ops, ref
+
+import _strict_ieee
 
 
 def _fields(shape, seed=0):
@@ -19,9 +23,13 @@ def _fields(shape, seed=0):
     return p_prev, p_cur, vel2
 
 
-@pytest.mark.parametrize(
-    "shape", [(8, 8, 8), (4, 8, 16), (16, 16, 16), (12, 20, 32)]
-)
+# small shapes that exercise every tiling edge: one z/y tile, several,
+# extents that do not divide the tile, and x wider than one lane tile
+SHAPES = [(8, 8, 8), (4, 8, 16), (16, 16, 16), (12, 20, 32),
+          (20, 12, 130), (5, 9, 3)]
+
+
+@pytest.mark.parametrize("shape", SHAPES)
 def test_kernel_matches_ref(shape):
     p_prev, p_cur, vel2 = _fields(shape)
     ppad, cpad = ref.pad_bc(p_prev), ref.pad_bc(p_cur)
@@ -87,3 +95,32 @@ def test_pallas_temporal_steps():
     np.testing.assert_allclose(
         np.asarray(pc1), np.asarray(pc2), rtol=1e-5, atol=1e-5
     )
+
+
+_STRICT_STEP = """
+import json, numpy as np, jax.numpy as jnp
+from repro.kernels.stencil import kernel, ref
+out = {}
+for shape in %s:
+    rng = np.random.default_rng(sum(shape))
+    f = lambda s: jnp.asarray(rng.standard_normal(s).astype(np.float32))
+    padded = tuple(n + 2 * ref.HALO for n in shape)
+    ppad, cpad, v = f(padded), f(padded), 0.05 + 0.01 * f(shape)
+    want = ref.wave_step(ppad, cpad, v)
+    got = kernel.wave_step_pallas(ppad, cpad, v)
+    out[str(shape)] = [bool(np.array_equal(a, b)) for a, b in zip(want, got)]
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def strict_step():
+    return _strict_ieee.run(_STRICT_STEP % json.dumps(SHAPES))
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_kernel_bit_identical_strict_ieee(strict_step, shape):
+    """With every operation rounded (no FMA contraction, see
+    ``_strict_ieee``), the single-step kernel is bit-identical to
+    ``ref.wave_step`` — p_next and lap — with arbitrary halo values."""
+    assert strict_step[str(list(shape))] == [True, True]

@@ -63,12 +63,14 @@ def test_kernel_special_values(ndim):
 
 
 def test_payload_sizing():
-    # fixed-rate: payload size is exactly nb * ceil(payload_bits / 32)
+    # fixed-rate: payload size is exactly nb * ceil(payload_bits / 32),
+    # stored word-major (W, nb) like every coefficient-major codec array
     x = _data((16, 16, 16), seed=0)
     for planes in PLANES:
         c = ops.compress(x, planes=planes, ndim=3)
         nb = (16 // 4) ** 3
-        assert c.payload.shape == (nb, ref.payload_words(3, planes))
+        assert c.payload.shape == (ref.payload_words(3, planes), nb)
+        assert c.emax.shape == (nb,)
         assert c.payload.dtype == jnp.uint32
         # exact fixed rate: subband offsets are zero-sum (or disabled)
         assert ref.payload_bits(3, planes) == 64 * min(planes, 32)
@@ -94,23 +96,23 @@ def test_tile_padding_edge():
 
 
 def test_bucket_tile_bounds_recompilation():
-    """Pad-to-tile sizes are power-of-two bucketed (capped at
-    DEFAULT_TILE_BLOCKS) so differently-sized units — e.g. an R unit's
-    blocks vs a C unit's — map to a handful of kernel tiles instead of
-    one compile per distinct block count."""
-    assert ops.bucket_tile(1) == 1
-    assert ops.bucket_tile(3) == 4
-    assert ops.bucket_tile(4) == 4
-    assert ops.bucket_tile(5) == 8
-    assert ops.bucket_tile(200) == kernel.DEFAULT_TILE_BLOCKS
+    """Pad-to-tile sizes are power-of-two bucketed (one 128-block lane
+    row up to DEFAULT_TILE_BLOCKS) so differently-sized units — e.g. an
+    R unit's blocks vs a C unit's — map to a handful of kernel tiles
+    instead of one compile per distinct block count."""
+    assert ops.bucket_tile(1) == kernel.LANES
+    assert ops.bucket_tile(128) == 128
+    assert ops.bucket_tile(129) == 256
+    assert ops.bucket_tile(600) == 1024
+    assert ops.bucket_tile(1024) == kernel.DEFAULT_TILE_BLOCKS
     assert ops.bucket_tile(10_000) == kernel.DEFAULT_TILE_BLOCKS
-    # every block count in an R/C-sized range shares <= log2 tiles
-    tiles = {ops.bucket_tile(nb) for nb in range(1, 257)}
-    assert len(tiles) == 9  # 1,2,4,...,256
+    # every block count maps to one of four tiles: 128, 256, 512, 1024
+    tiles = {ops.bucket_tile(nb) for nb in range(1, 4097)}
+    assert tiles == {128, 256, 512, 1024}
     # bucketed padding stays bit-identical to the oracle across bucket
     # boundaries (pad rows are encoded then stripped)
-    for planes_z in (4, 8, 20):  # 1, 2, 5 z-blocks -> tiles differ
-        x = _data((planes_z, 8, 8), seed=planes_z)
+    for planes_z in (4, 36, 80):  # 36, 324, 720 blocks -> tiles differ
+        x = _data((planes_z, 24, 24), seed=planes_z)
         cp = ops.compress(x, planes=12, ndim=3, backend="pallas")
         cr = ops.compress(x, planes=12, ndim=3, backend="ref")
         np.testing.assert_array_equal(
@@ -120,6 +122,26 @@ def test_bucket_tile_bounds_recompilation():
             np.asarray(ops.decompress(cp, backend="pallas")),
             np.asarray(ops.decompress(cr, backend="ref")),
         )
+
+
+@pytest.mark.parametrize(
+    "shape",
+    # 1 block, a 256-block tile, one full 1024-block tile, and a unit
+    # over several grid steps of the kernel (1280 blocks)
+    [(4, 4, 4), (8, 32, 36), (16, 64, 64), (20, 64, 64)],
+)
+def test_kernel_tile_layouts_bitwise(shape):
+    """Encode and decode through every tile bucket and a multi-step
+    grid are bit-identical to the oracle."""
+    x = _data(shape, seed=sum(shape))
+    cr = ops.compress(x, planes=12, ndim=3, backend="ref")
+    cp = ops.compress(x, planes=12, ndim=3, backend="pallas")
+    np.testing.assert_array_equal(np.asarray(cr.payload), np.asarray(cp.payload))
+    np.testing.assert_array_equal(np.asarray(cr.emax), np.asarray(cp.emax))
+    np.testing.assert_array_equal(
+        np.asarray(ops.decompress(cp, backend="pallas")),
+        np.asarray(ops.decompress(cr, backend="ref")),
+    )
 
 
 def test_decompress_units_batched_matches_single():

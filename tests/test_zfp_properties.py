@@ -43,7 +43,7 @@ def test_error_bound_holds(x, planes):
     emax = ref.block_emax(xb)
     y = ref.quantize_blocks(xb, planes, 3)
     bound = ref.max_abs_error_bound(emax, planes, 3, jnp.float32)
-    err = jnp.max(jnp.abs(y - xb), axis=-1)
+    err = jnp.max(jnp.abs(y - xb), axis=0)
     assert bool(jnp.all(err <= bound + 1e-37)), (
         float(jnp.max(err - bound)),
         planes,
