@@ -110,8 +110,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import checkpoint as ckpt
+from repro.core import spans
 from repro.core.outofcore import HostUnitStore, OOCConfig, unit_shards
 from repro.core.ratecontrol import RateController, rate_label
+from repro.core.spans import span
 from repro.core.taskgraph import (
     Schedule,
     Task,
@@ -528,22 +530,25 @@ class AsyncExecutor:
         refused) pays here.
         """
         sweep_no, parked = self._pending.popleft()
-        for task, value, raw, ver in parked:
-            kind, idx = task.unit
-            if self.cache.enabled and self.cache.write_back:
-                if self.store.version_of(task.field, kind, idx) >= ver:
-                    continue  # an eviction flush already committed this
-                ent = self.cache.peek((task.field, task.unit))
-                if ent is not None and ent.dirty and ent.version >= ver:
-                    self.store.commit_device(task.field, kind, idx, ver)
-                    continue
-            wire = self.store.put(
-                task.field, kind, idx, value, version=ver
-            )
-            self.transfers.append(Transfer(
-                "d2h", task.field, task.unit, raw, wire,
-                sweep_no, task.block,
-            ))
+        if not parked:  # every writeback of the visit committed early
+            return
+        with span(spans.DRAIN, round=sweep_no, block=parked[0][0].block):
+            for task, value, raw, ver in parked:
+                kind, idx = task.unit
+                if self.cache.enabled and self.cache.write_back:
+                    if self.store.version_of(task.field, kind, idx) >= ver:
+                        continue  # an eviction flush already committed it
+                    ent = self.cache.peek((task.field, task.unit))
+                    if ent is not None and ent.dirty and ent.version >= ver:
+                        self.store.commit_device(task.field, kind, idx, ver)
+                        continue
+                wire = self.store.put(
+                    task.field, kind, idx, value, version=ver
+                )
+                self.transfers.append(Transfer(
+                    "d2h", task.field, task.unit, raw, wire,
+                    sweep_no, task.block,
+                ))
 
     def _drain_all(self) -> None:
         while self._pending:
@@ -615,10 +620,11 @@ class AsyncExecutor:
             k for k in ((t.field, t.unit) for t in tasks)
             if k in self._staged
         ]
-        decoded = zfp_ops.decompress_units(
-            [self._staged.pop(k) for k in keys],
-            backend=self.cfg.backend,
-        )
+        with span(spans.DECODE, block=tasks[0].block):
+            decoded = zfp_ops.decompress_units(
+                [self._staged.pop(k) for k in keys],
+                backend=self.cfg.backend,
+            )
         for k, arr in zip(keys, decoded):
             self._dev[k] = arr
 
@@ -662,37 +668,38 @@ class AsyncExecutor:
         writeback units. Returns the carry (time-t common regions) for
         block i+1. ``kr`` is the number of sweeps this visit fuses
         (== schedule temporal, except a truncated final round)."""
-        cfg, plan = self.cfg, self.plan
-        h, b = plan.halo, plan.block
-        dev: Dict[str, jax.Array] = {}
-        new_shared: Dict[str, jax.Array] = {}
-        for name in cfg.fields:
-            arr = self._assemble(name, i, shared[name])
-            if i < plan.ndiv - 1:
-                new_shared[name] = arr[b : b + 2 * h]
-            dev[name] = arr
-        pp, pc = stencil_ops.fused_temporal_steps(
-            dev["p_prev"], dev["p_cur"], dev["vel2"],
-            steps=cfg.bt * kr, backend=cfg.backend,
-        )
-        s, _ = plan.owned(i)
-        itemsize = jnp.dtype(cfg.dtype).itemsize
-        for name, new in (("p_prev", pp), ("p_cur", pc)):
-            owned = new[h : h + b]
-            for kind, idx in plan.writeback_units(i):
-                if kind == "R":
-                    rlo, rhi = plan.remainder(i)
-                    val = owned[rlo - s : rhi - s]
-                else:  # completed C_{i-1}: held lower half + our upper
-                    val = jnp.concatenate(
-                        [held[name + str(i - 1)], owned[:h]]
+        with span(spans.STENCIL, block=i):
+            cfg, plan = self.cfg, self.plan
+            h, b = plan.halo, plan.block
+            dev: Dict[str, jax.Array] = {}
+            new_shared: Dict[str, jax.Array] = {}
+            for name in cfg.fields:
+                arr = self._assemble(name, i, shared[name])
+                if i < plan.ndiv - 1:
+                    new_shared[name] = arr[b : b + 2 * h]
+                dev[name] = arr
+            pp, pc = stencil_ops.fused_temporal_steps(
+                dev["p_prev"], dev["p_cur"], dev["vel2"],
+                steps=cfg.bt * kr, backend=cfg.backend,
+            )
+            s, _ = plan.owned(i)
+            itemsize = jnp.dtype(cfg.dtype).itemsize
+            for name, new in (("p_prev", pp), ("p_cur", pc)):
+                owned = new[h : h + b]
+                for kind, idx in plan.writeback_units(i):
+                    if kind == "R":
+                        rlo, rhi = plan.remainder(i)
+                        val = owned[rlo - s : rhi - s]
+                    else:  # completed C_{i-1}: held lower half + our upper
+                        val = jnp.concatenate(
+                            [held[name + str(i - 1)], owned[:h]]
+                        )
+                    self._outvals[(name, (kind, idx))] = val
+                    self._outraw[(name, (kind, idx))] = (
+                        int(val.size) * itemsize
                     )
-                self._outvals[(name, (kind, idx))] = val
-                self._outraw[(name, (kind, idx))] = (
-                    int(val.size) * itemsize
-                )
-            if i < plan.ndiv - 1:
-                held[name + str(i)] = owned[b - h : b]
+                if i < plan.ndiv - 1:
+                    held[name + str(i)] = owned[b - h : b]
         return {n: new_shared.get(n) for n in cfg.fields}
 
     def _exec_compress(self, tasks: List[Task]) -> None:
@@ -705,40 +712,43 @@ class AsyncExecutor:
         skip the codec and commit raw, and every encode feeds the
         controller one observation (measured round-trip error at the
         actual rate, and the unit's amplitude)."""
-        by_planes: Dict[int, List[Task]] = {}
-        for t in tasks:
-            kind, idx = t.unit
-            if self.rates is not None:
-                planes = self.rates.rate_for(
-                    t.field, kind, idx, self.sweeps_done
-                )
-            else:
-                planes = self.cfg.fields[t.field].planes
-            if planes is None:
-                # lossless commit: the raw array ships as-is, error 0
-                val = self._outvals[(t.field, t.unit)]
-                self.rates.observe(
-                    t.field, kind, idx, None, 0.0,
-                    float(jnp.max(jnp.abs(val))),
-                )
-                continue
-            by_planes.setdefault(planes, []).append(t)
-        for planes, ts in by_planes.items():
-            vals = [self._outvals[(t.field, t.unit)] for t in ts]
-            encoded = zfp_ops.compress_units(
-                vals, planes=planes, ndim=3, backend=self.cfg.backend,
-            )
-            if self.rates is not None:
-                for t, v in zip(ts, vals):
-                    kind, idx = t.unit
-                    q = zfp_ops.quantize(v, planes=planes, ndim=3)
-                    self.rates.observe(
-                        t.field, kind, idx, planes,
-                        float(jnp.max(jnp.abs(q - v))),
-                        float(jnp.max(jnp.abs(v))),
+        if not tasks:
+            return
+        with span(spans.ENCODE, block=tasks[0].block):
+            by_planes: Dict[int, List[Task]] = {}
+            for t in tasks:
+                kind, idx = t.unit
+                if self.rates is not None:
+                    planes = self.rates.rate_for(
+                        t.field, kind, idx, self.sweeps_done
                     )
-            for t, c in zip(ts, encoded):
-                self._outvals[(t.field, t.unit)] = c
+                else:
+                    planes = self.cfg.fields[t.field].planes
+                if planes is None:
+                    # lossless commit: the raw array ships as-is, error 0
+                    val = self._outvals[(t.field, t.unit)]
+                    self.rates.observe(
+                        t.field, kind, idx, None, 0.0,
+                        float(jnp.max(jnp.abs(val))),
+                    )
+                    continue
+                by_planes.setdefault(planes, []).append(t)
+            for planes, ts in by_planes.items():
+                vals = [self._outvals[(t.field, t.unit)] for t in ts]
+                encoded = zfp_ops.compress_units(
+                    vals, planes=planes, ndim=3, backend=self.cfg.backend,
+                )
+                if self.rates is not None:
+                    for t, v in zip(ts, vals):
+                        kind, idx = t.unit
+                        q = zfp_ops.quantize(v, planes=planes, ndim=3)
+                        self.rates.observe(
+                            t.field, kind, idx, planes,
+                            float(jnp.max(jnp.abs(q - v))),
+                            float(jnp.max(jnp.abs(v))),
+                        )
+                for t, c in zip(ts, encoded):
+                    self._outvals[(t.field, t.unit)] = c
 
     def _flush_entry(
         self, key: UnitKey, ent: Entry, block: int, mark: bool = False,
@@ -816,58 +826,63 @@ class AsyncExecutor:
         """
         kr = self.temporal if sweeps is None else sweeps
         assert 1 <= kr <= self.temporal, (kr, self.temporal)
-        plan = self.plan
-        rw = [n for n, sp in self.cfg.fields.items() if sp.role == "rw"]
-        held: Dict[str, jax.Array] = {}
-        if self.shard is not None and not self.shard.first:
-            # the left neighbor's held slices seed the boundary
-            # writeback concat exactly as block lo-1's visit would
-            lo = self._blocks[0]
-            for n in rw:
-                held[n + str(lo - 1)] = self._held_in.pop(n)
-        shared: Dict[str, Optional[jax.Array]] = {
-            n: None for n in self.cfg.fields
-        }
-        for j, i in enumerate(self._blocks):
-            btasks = self._by_block[j]
-            # window admission precedes this visit's first transfer
-            self._admit()
-            # one chunk of an in-flight overlapped snapshot drains
-            # here, interleaved with this visit's fetch/compute — the
-            # snapshot's flush-D2H rides the sweep instead of stalling
-            # it (same cadence the checkpoint-aware graph replays)
-            self._drain_ckpt(paced=True)
-            for t in (t for t in btasks if t.kind == "h2d"):
-                self._exec_h2d(t)
-            self._exec_decompress(
-                [t for t in btasks if t.kind == "decompress"]
-            )
-            shared = self._exec_stencil(i, shared, held, kr)
-            self._exec_compress(
-                [t for t in btasks if t.kind == "compress"]
-            )
-            # capture the boundary-common export BEFORE parking pops
-            # the payload: the halo ships the same encoded object the
-            # writeback commits, at the version the park will issue
-            for t in btasks:
-                if t.kind == "halo" and ".halo." in t.tid:
-                    key = (t.field, t.unit)
-                    self._halo_out[key] = (
-                        self._outvals[key],
-                        self._ver.get(key, 0) + kr,
+        with span(spans.ROUND, round=self.sweeps_done, sweeps=kr):
+            plan = self.plan
+            rw = [n for n, sp in self.cfg.fields.items() if sp.role == "rw"]
+            held: Dict[str, jax.Array] = {}
+            if self.shard is not None and not self.shard.first:
+                # the left neighbor's held slices seed the boundary
+                # writeback concat exactly as block lo-1's visit would
+                lo = self._blocks[0]
+                for n in rw:
+                    held[n + str(lo - 1)] = self._held_in.pop(n)
+            shared: Dict[str, Optional[jax.Array]] = {
+                n: None for n in self.cfg.fields
+            }
+            for j, i in enumerate(self._blocks):
+                with span(spans.VISIT, round=self.sweeps_done, block=i):
+                    btasks = self._by_block[j]
+                    # window admission precedes this visit's first
+                    # transfer
+                    self._admit()
+                    # one chunk of an in-flight overlapped snapshot
+                    # drains here, interleaved with this visit's
+                    # fetch/compute — the snapshot's flush-D2H rides the
+                    # sweep instead of stalling it (same cadence the
+                    # checkpoint-aware graph replays)
+                    self._drain_ckpt(paced=True)
+                    for t in (t for t in btasks if t.kind == "h2d"):
+                        self._exec_h2d(t)
+                    self._exec_decompress(
+                        [t for t in btasks if t.kind == "decompress"]
                     )
-            self._park_writebacks(btasks, kr)
-        if self.shard is not None and not self.shard.last:
-            last = self._blocks[-1]
-            self._held_out = {n: held[n + str(last)] for n in rw}
-        assert not self._dev and not self._staged and not self._outvals
-        self.sweeps_done += kr
-        if self.rates is not None:
-            # sweep boundary: re-decide the rate map from this round's
-            # observations (applies from the next sweep on) — the same
-            # point the synchronous engine decides, so both engines
-            # record identical decision logs
-            self.rates.decide(self.sweeps_done)
+                    shared = self._exec_stencil(i, shared, held, kr)
+                    self._exec_compress(
+                        [t for t in btasks if t.kind == "compress"]
+                    )
+                    # capture the boundary-common export BEFORE parking
+                    # pops the payload: the halo ships the same encoded
+                    # object the writeback commits, at the version the
+                    # park will issue
+                    for t in btasks:
+                        if t.kind == "halo" and ".halo." in t.tid:
+                            key = (t.field, t.unit)
+                            self._halo_out[key] = (
+                                self._outvals[key],
+                                self._ver.get(key, 0) + kr,
+                            )
+                    self._park_writebacks(btasks, kr)
+            if self.shard is not None and not self.shard.last:
+                last = self._blocks[-1]
+                self._held_out = {n: held[n + str(last)] for n in rw}
+            assert not self._dev and not self._staged and not self._outvals
+            self.sweeps_done += kr
+            if self.rates is not None:
+                # sweep boundary: re-decide the rate map from this
+                # round's observations (applies from the next sweep on)
+                # — the same point the synchronous engine decides, so
+                # both engines record identical decision logs
+                self.rates.decide(self.sweeps_done)
 
     def finish(self) -> None:
         """Drain the window: every issued writeback is *committed* —
@@ -876,8 +891,9 @@ class AsyncExecutor:
         call ``flush()`` (or ``gather()``, which does) before any
         host-side read of the store. An in-flight overlapped snapshot
         is force-completed first."""
-        self._drain_ckpt()
-        self._drain_all()
+        with span(spans.FINISH):
+            self._drain_ckpt()
+            self._drain_all()
 
     def flush(self) -> int:
         """Flush-on-demand: materialize every dirty-resident payload to
@@ -896,39 +912,40 @@ class AsyncExecutor:
         prices the corresponding spare-stream win — see
         ``repro.core.pipeline.simulate``).
         """
-        self._drain_ckpt()  # release snapshot pins before flushing
-        n = 0
-        for key, ent in self.cache.dirty_entries():
-            t0 = self._timer()
-            reissued = False
-            try:
-                self._flush_entry(key, ent, -1, mark=True)
-            except Exception:
-                if self.reissue is None:
-                    raise
-                # spare-stream reissue: the straggling/failed attempt
-                # is abandoned and the payload re-put once; a second
-                # failure propagates (the entry stays dirty for retry)
-                self._flush_entry(key, ent, -1, mark=True, reissued=True)
-                self.cache.stats.flush_reissues += 1
-                reissued = True
-            elapsed = self._timer() - t0
-            # a reissued put already counted as a fault: its two-
-            # attempt elapsed neither flags a straggler nor enters the
-            # rolling median (it would inflate the baseline)
-            if not reissued:
-                if (
-                    self.reissue is not None
-                    and self._flush_times
-                    and self.reissue.should_reissue(
-                        elapsed, statistics.median(self._flush_times)
-                    )
-                ):
-                    self.cache.stats.flush_stragglers += 1
-                self._flush_times.append(elapsed)
-                if len(self._flush_times) > 64:  # rolling window
-                    self._flush_times.pop(0)
-            n += 1
+        with span(spans.FLUSH):
+            self._drain_ckpt()  # release snapshot pins before flushing
+            n = 0
+            for key, ent in self.cache.dirty_entries():
+                t0 = self._timer()
+                reissued = False
+                try:
+                    self._flush_entry(key, ent, -1, mark=True)
+                except Exception:
+                    if self.reissue is None:
+                        raise
+                    # spare-stream reissue: the straggling/failed attempt
+                    # is abandoned and the payload re-put once; a second
+                    # failure propagates (the entry stays dirty for retry)
+                    self._flush_entry(key, ent, -1, mark=True, reissued=True)
+                    self.cache.stats.flush_reissues += 1
+                    reissued = True
+                elapsed = self._timer() - t0
+                # a reissued put already counted as a fault: its two-
+                # attempt elapsed neither flags a straggler nor enters the
+                # rolling median (it would inflate the baseline)
+                if not reissued:
+                    if (
+                        self.reissue is not None
+                        and self._flush_times
+                        and self.reissue.should_reissue(
+                            elapsed, statistics.median(self._flush_times)
+                        )
+                    ):
+                        self.cache.stats.flush_stragglers += 1
+                    self._flush_times.append(elapsed)
+                    if len(self._flush_times) > 64:  # rolling window
+                        self._flush_times.pop(0)
+                n += 1
         return n
 
     def run(

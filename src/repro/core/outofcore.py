@@ -53,7 +53,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.blocks import BlockPlan
+from repro.core.spans import span
 from repro.core.taskgraph import Transfer, summarize_transfers
 from repro.distributed.fault import (
     ChecksumError,
@@ -198,18 +200,13 @@ def unit_checksum(value, version: int) -> int:
     payload can never pass as a newer one. Computed from *host* bytes
     (for device values ``np.asarray`` is the materialization — callers
     on hot paths pass the already-materialized copy)."""
-    crc = zlib.crc32(str(int(version)).encode())
-    if isinstance(value, Compressed):
-        crc = zlib.crc32(
-            np.ascontiguousarray(np.asarray(value.payload)).tobytes(), crc
-        )
-        crc = zlib.crc32(
-            np.ascontiguousarray(np.asarray(value.emax)).tobytes(), crc
-        )
-    else:
-        crc = zlib.crc32(
-            np.ascontiguousarray(np.asarray(value)).tobytes(), crc
-        )
+    parts = ((value.payload, value.emax) if isinstance(value, Compressed)
+             else (value,))
+    parts = [np.ascontiguousarray(np.asarray(p)) for p in parts]
+    with span(spans.CHECKSUM, bytes=sum(p.nbytes for p in parts)):
+        crc = zlib.crc32(str(int(version)).encode())
+        for p in parts:
+            crc = zlib.crc32(p.tobytes(), crc)
     return crc & 0xFFFFFFFF
 
 
@@ -421,26 +418,33 @@ class HostUnitStore:
         if version is None:
             version = self._versions.get(key, -1) + 1
         assert version >= self._host_versions.get(key, 0), key
-        # materialize once — for device values this is the D2H
-        if isinstance(value, Compressed):
-            host: object = Compressed(
-                np.asarray(value.payload), np.asarray(value.emax),
-                value.shape, value.planes, value.ndim_spatial, value.dtype,
-            )
-            wire = host.nbytes()
-        else:
-            host = np.asarray(value)
-            wire = host.nbytes
-        crc = unit_checksum(host, version)
-        if on_wire:
-            host = self._wire(op, field, kind, idx, version, host, crc)
-        # store the payload BEFORE advancing the version maps: a put
-        # that fails mid-copy must not leave host_current() true over
-        # stale bytes (the flush-retry contract relies on this order)
-        self._units[key] = host
-        self._crc[key] = crc
-        self._versions[key] = max(version, self._versions.get(key, 0))
-        self._host_versions[key] = version
+        compressed = isinstance(value, Compressed)
+        wire = int(value.nbytes() if compressed else value.nbytes)
+        with span(spans.PUT, field=field, unit=f"{kind}{idx}", bytes=wire,
+                  op=op):
+            with span(spans.WAIT):
+                jax.block_until_ready(value)
+            # materialize once — for device values this is the D2H
+            with span(spans.D2H):
+                if compressed:
+                    host: object = Compressed(
+                        np.asarray(value.payload), np.asarray(value.emax),
+                        value.shape, value.planes, value.ndim_spatial,
+                        value.dtype,
+                    )
+                else:
+                    host = np.asarray(value)
+            crc = unit_checksum(host, version)
+            if on_wire:
+                host = self._wire(op, field, kind, idx, version, host, crc)
+            # store the payload BEFORE advancing the version maps: a put
+            # that fails mid-copy must not leave host_current() true over
+            # stale bytes (the flush-retry contract relies on this order).
+            # The replaced payload is freed here, inside the span.
+            self._units[key] = host
+            self._crc[key] = crc
+            self._versions[key] = max(version, self._versions.get(key, 0))
+            self._host_versions[key] = version
         return wire
 
     def get(self, field: str, kind: str, idx: int):
@@ -643,21 +647,28 @@ class HostUnitStore:
         key = (field, kind, idx)
         stored = self.get(field, kind, idx)
         version = self._host_versions.get(key, 0)
-        crc = self._crc.get(key)
-        if crc is None:  # pre-digest stores (legacy direct loads)
-            crc = self._crc[key] = unit_checksum(stored, version)
-        stored = self._wire(
-            "h2d", field, kind, idx, version, stored, crc
-        )
-        if isinstance(stored, Compressed):
-            dev = Compressed(
-                jnp.asarray(stored.payload), jnp.asarray(stored.emax),
-                stored.shape, stored.planes, stored.ndim_spatial,
-                stored.dtype,
+        compressed = isinstance(stored, Compressed)
+        wire = int(stored.nbytes() if compressed else stored.nbytes)
+        with span(spans.STAGE, field=field, unit=f"{kind}{idx}", bytes=wire):
+            crc = self._crc.get(key)
+            if crc is None:  # pre-digest stores (legacy direct loads)
+                crc = self._crc[key] = unit_checksum(stored, version)
+            stored = self._wire(
+                "h2d", field, kind, idx, version, stored, crc
             )
+            with span(spans.H2D):
+                if compressed:
+                    dev = Compressed(
+                        jnp.asarray(stored.payload), jnp.asarray(stored.emax),
+                        stored.shape, stored.planes, stored.ndim_spatial,
+                        stored.dtype,
+                    )
+                else:
+                    dev = jnp.asarray(stored)
+        if compressed:
             raw = int(np.prod(stored.shape)) * np.dtype(stored.dtype).itemsize
-            return dev, raw, stored.nbytes()
-        return jnp.asarray(stored), stored.nbytes, stored.nbytes
+            return dev, raw, wire
+        return dev, wire, wire
 
     def checksum_of(self, field: str, kind: str, idx: int) -> int:
         """The recorded integrity digest of the committed host

@@ -1,0 +1,198 @@
+"""The engine's host spans (``repro.core.spans``) under the profiler.
+
+A tiny ``AsyncExecutor`` runs two rounds under ``jax.profiler``, once
+streamed (no residency: every unit crosses the link) and once resident
+(write-back residency: the flush is the only put). The trace file is
+read back with ``ProfileData`` and the spans checked against the
+executor's own transfer log: every documented span is there, nested as
+the engine calls it, one crossing span per transfer record, and the
+jitted programs keep their module names.
+"""
+
+import glob
+import os
+import pathlib
+from collections import Counter
+
+import jax
+import numpy as np
+import pytest
+
+from repro.core import spans
+from repro.core.executor import AsyncExecutor
+from repro.core.outofcore import OOCConfig, paper_code_fields
+from repro.kernels.stencil import ref as stencil_ref
+
+SHAPE = (96, 12, 12)
+NDIV = 3
+ROUNDS = 2
+FAR = 1 << 40
+MODES = {"streamed": 0, "resident": 1 << 30}
+EVERY = {v for k, v in vars(spans).items() if k.isupper()}
+# what the resident run never does after its warm round: no unit
+# crosses host -> device, and no writeback is put before the flush
+RESIDENT_ABSENT = {spans.STAGE, spans.H2D}
+
+
+class Span:
+    def __init__(self, event):
+        self.name = event.name
+        self.start = event.start_ns
+        self.end = event.start_ns + event.duration_ns
+        self.meta = {k: v for k, v in event.stats}
+
+    def inside(self, other) -> bool:
+        return other.start <= self.start and self.end <= other.end
+
+
+def _record(tmp_path, cache_bytes):
+    p_cur = np.asarray(stencil_ref.ricker_source(SHAPE), dtype=np.float32)
+    cfg = OOCConfig(SHAPE, NDIV, 2, paper_code_fields(4))
+    eng = AsyncExecutor(cfg, 0.95 * p_cur, p_cur,
+                        np.full(SHAPE, 0.07, np.float32),
+                        schedule="depth2", cache_bytes=cache_bytes)
+    eng.advance_round(FAR)  # compiles, and fills the residency
+    eng.finish()
+    n0 = len(eng.transfers)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        for _ in range(ROUNDS):
+            eng.advance_round(FAR)
+        eng.finish()
+        eng.flush()
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                        recursive=True)
+    events, modules = [], set()
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("ooc."):
+                    events.append(Span(e))
+                for key, value in e.stats:
+                    if key == "hlo_module":
+                        modules.add(str(value).split("(", 1)[0])
+    return {"spans": events, "modules": modules,
+            "transfers": eng.transfers[n0:]}
+
+
+@pytest.fixture(scope="module", params=sorted(MODES))
+def traced(request, tmp_path_factory):
+    got = _record(tmp_path_factory.mktemp(request.param),
+                  MODES[request.param])
+    got["mode"] = request.param
+    return got
+
+
+def _named(traced, name):
+    return [s for s in traced["spans"] if s.name == name]
+
+
+def _within(child, parents):
+    return [p for p in parents if child.inside(p)]
+
+
+def test_every_documented_span_appears(traced):
+    want = EVERY - (RESIDENT_ABSENT if traced["mode"] == "resident"
+                    else set())
+    names = {s.name for s in traced["spans"]}
+    assert want <= names, want - names
+    assert names <= EVERY, names - EVERY
+
+
+def test_spans_nest_as_the_engine_calls_them(traced):
+    stores = _named(traced, spans.STAGE) + _named(traced, spans.PUT)
+    for s in _named(traced, spans.CHECKSUM):
+        assert _within(s, stores), "a digest outside every crossing"
+    for name, parent in ((spans.WAIT, spans.PUT), (spans.D2H, spans.PUT),
+                         (spans.H2D, spans.STAGE),
+                         (spans.VISIT, spans.ROUND)):
+        parents = _named(traced, parent)
+        for s in _named(traced, name):
+            assert len(_within(s, parents)) == 1, (name, parent)
+    # every crossing happens inside a visit, a finish or a flush
+    outer = [s for s in traced["spans"]
+             if s.name in (spans.VISIT, spans.FINISH, spans.FLUSH)]
+    for s in stores:
+        assert _within(s, outer), s.name
+
+
+def test_each_round_visits_every_block_once(traced):
+    rounds = _named(traced, spans.ROUND)
+    assert len(rounds) == ROUNDS
+    for r in rounds:
+        assert r.meta["sweeps"] == 1
+        visits = [v for v in _named(traced, spans.VISIT) if v.inside(r)]
+        assert [v.meta["block"] for v in visits] == list(range(NDIV))
+        assert {v.meta["round"] for v in visits} == {r.meta["round"]}
+    assert [r.meta["round"] for r in rounds] == [1, 2]
+
+
+def test_one_crossing_span_per_transfer_record(traced):
+    log = Counter(t.direction for t in traced["transfers"])
+    stages, puts = _named(traced, spans.STAGE), _named(traced, spans.PUT)
+    assert len(stages) == log["h2d"]
+    assert len(puts) == log["d2h"]
+    if traced["mode"] == "streamed":
+        assert log["h2d"] and log["d2h"]
+    for op, got in (("h2d", stages), ("d2h", puts)):
+        assert sum(s.meta["bytes"] for s in got) == sum(
+            t.wire_bytes for t in traced["transfers"] if t.direction == op)
+
+
+def test_digests_per_crossing(traced):
+    """A put digests its source bytes and then the received copy; a
+    stage digests the received copy once."""
+    digests = _named(traced, spans.CHECKSUM)
+    for name, want in ((spans.PUT, 2), (spans.STAGE, 1)):
+        for s in _named(traced, name):
+            inner = [d for d in digests if d.inside(s)]
+            assert len(inner) == want, name
+            assert sum(d.meta["bytes"] for d in inner) >= s.meta["bytes"]
+
+
+def test_metadata(traced):
+    keys = {
+        spans.ROUND: {"round", "sweeps"},
+        spans.VISIT: {"round", "block"},
+        spans.DRAIN: {"round", "block"},
+        spans.DECODE: {"block"},
+        spans.STENCIL: {"block"},
+        spans.ENCODE: {"block"},
+        spans.STAGE: {"field", "unit", "bytes"},
+        spans.PUT: {"field", "unit", "bytes", "op"},
+        spans.CHECKSUM: {"bytes"},
+        spans.WAIT: set(), spans.D2H: set(), spans.H2D: set(),
+        spans.FINISH: set(), spans.FLUSH: set(),
+    }
+    assert set(keys) == EVERY
+    for s in traced["spans"]:
+        assert set(s.meta) == keys[s.name], s.name
+    for s in _named(traced, spans.PUT):
+        assert s.meta["op"] == "d2h"
+        assert s.meta["field"] in ("p_prev", "p_cur")
+
+
+def test_a_drain_retires_an_earlier_visit(traced):
+    """``ooc.drain`` names the visit whose writebacks it retires: one
+    that started before the drain."""
+    visits = {(v.meta["round"], v.meta["block"]): v
+              for v in _named(traced, spans.VISIT)}
+    drains = _named(traced, spans.DRAIN)
+    assert drains
+    for d in drains:
+        v = visits.get((d.meta["round"], d.meta["block"]))
+        assert v is None or v.start < d.start
+
+
+def test_the_jitted_programs_keep_their_module_names(traced):
+    assert {"jit_fused_temporal_steps", "jit_compress",
+            "jit_decompress"} <= traced["modules"]
+
+
+def test_spans_are_made_by_the_one_helper():
+    src = pathlib.Path(spans.__file__).resolve().parents[1]
+    users = [p.relative_to(src).as_posix() for p in src.rglob("*.py")
+             if "TraceAnnotation" in p.read_text()]
+    assert users == ["core/spans.py"]
