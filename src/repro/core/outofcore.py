@@ -44,8 +44,11 @@ velocity field (transferred to device, never written back).
 
 from __future__ import annotations
 
+import functools
+import os
 import zlib
 from collections import Counter
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, List, Literal, Optional, Sequence, Tuple
 
@@ -72,6 +75,7 @@ from repro.kernels.zfp.ref import Compressed
 __all__ = [
     "FieldSpec", "OOCConfig", "OutOfCoreWave", "HostUnitStore",
     "Transfer", "paper_code_fields", "unit_shards", "unit_checksum",
+    "crc32_combine",
 ]
 
 Role = Literal["rw", "ro"]
@@ -194,19 +198,85 @@ def paper_code_fields(code: int, f32: bool = True) -> Dict[str, FieldSpec]:
     raise ValueError(code)
 
 
+# crc32 over large buffers: one zlib call up to DIGEST_SPLIT bytes;
+# above it, DIGEST_CHUNK-byte chunks digested on a thread pool (zlib
+# releases the GIL) and joined in order by ``crc32_combine``. The value
+# is the one zlib call's either way.
+DIGEST_CHUNK = 32 << 20
+DIGEST_SPLIT = 64 << 20
+_CRC32_POLY = 0xEDB88320  # crc32's generator polynomial, bit-reflected
+
+
+def _gf2_mul(a: int, b: int) -> int:
+    """``a * b`` modulo crc32's polynomial (zlib's ``multmodp``; both
+    bit-reflected, x^0 in the top bit)."""
+    p = 0
+    for bit in range(31, -1, -1):
+        if a >> bit & 1:
+            p ^= b
+        b = (b >> 1) ^ _CRC32_POLY if b & 1 else b >> 1
+    return p
+
+
+@functools.lru_cache(maxsize=64)
+def _crc32_shift(nbytes: int) -> int:
+    """x^(8 * nbytes) modulo the polynomial: the GF(2) operator that
+    carries a crc past ``nbytes`` further bytes (zlib's ``x2nmodp``)."""
+    op, power, n = 1 << 31, 1 << 30, 8 * nbytes  # x^0, x^1
+    while n:
+        if n & 1:
+            op = _gf2_mul(power, op)
+        power = _gf2_mul(power, power)
+        n >>= 1
+    return op
+
+
+def crc32_combine(crc1: int, crc2: int, len2: int) -> int:
+    """``zlib.crc32(a + b)`` from ``crc1 = zlib.crc32(a)``, ``crc2 =
+    zlib.crc32(b)`` and ``len2 = len(b)`` (zlib's ``crc32_combine``)."""
+    return _gf2_mul(_crc32_shift(len2), crc1) ^ crc2
+
+
+@functools.lru_cache(maxsize=None)
+def _digest_pool() -> ThreadPoolExecutor:
+    return ThreadPoolExecutor(min(os.cpu_count() or 1, 8),
+                              thread_name_prefix="crc32")
+
+
+def _crc32(buf: np.ndarray, crc: int) -> int:
+    """``zlib.crc32`` of the C-contiguous ``buf``'s bytes, continuing
+    ``crc``, read in place (no copy)."""
+    flat = buf.reshape(-1).view(np.uint8)
+    if flat.size <= DIGEST_SPLIT:
+        return zlib.crc32(flat, crc)
+    chunks = [flat[i:i + DIGEST_CHUNK]
+              for i in range(0, flat.size, DIGEST_CHUNK)]
+    for chunk, got in zip(chunks, _digest_pool().map(zlib.crc32, chunks)):
+        crc = crc32_combine(crc, got, chunk.size)
+    return crc
+
+
+def _digest_parts(value) -> tuple:
+    """What ``unit_checksum`` digests of ``value``, in order."""
+    if isinstance(value, Compressed):
+        return value.payload, value.emax
+    return (value,)
+
+
 def unit_checksum(value, version: int) -> int:
     """crc32 integrity digest of one unit: payload (+emax for
     compressed units) chained with the version it realizes, so a stale
     payload can never pass as a newer one. Computed from *host* bytes
     (for device values ``np.asarray`` is the materialization — callers
-    on hot paths pass the already-materialized copy)."""
-    parts = ((value.payload, value.emax) if isinstance(value, Compressed)
-             else (value,))
-    parts = [np.ascontiguousarray(np.asarray(p)) for p in parts]
+    on hot paths pass the already-materialized copy), read in place;
+    a part over ``DIGEST_SPLIT`` bytes is digested in chunks on
+    several threads, to the same value."""
+    parts = [np.ascontiguousarray(np.asarray(p))
+             for p in _digest_parts(value)]
     with span(spans.CHECKSUM, bytes=sum(p.nbytes for p in parts)):
         crc = zlib.crc32(str(int(version)).encode())
         for p in parts:
-            crc = zlib.crc32(p.tobytes(), crc)
+            crc = _crc32(p, crc)
     return crc & 0xFFFFFFFF
 
 
@@ -293,8 +363,8 @@ class HostUnitStore:
         self._host_versions: Dict[Tuple[str, str, int], int] = {}
         # integrity digests of the committed host payloads (crc32 over
         # payload+emax+version, ``unit_checksum``): recorded at every
-        # put, verified at every fetch (h2d), every flush commit (d2h)
-        # and on restore — a corrupted unit is caught before any
+        # put, verified at every fetch (h2d), on every corrupted d2h
+        # copy and on restore — a corrupted unit is caught before any
         # stencil step can consume it
         self._crc: Dict[Tuple[str, str, int], int] = {}
         # the self-healing hooks: ``injector`` replays a FaultPlan on
@@ -310,6 +380,9 @@ class HostUnitStore:
         self.wire_stats: Dict[str, int] = {
             "h2d_retries": 0, "d2h_retries": 0, "wire_faults": 0,
             "checksum_failures": 0, "wire_stragglers": 0,
+            # bytes read by the digests of puts, crossings and
+            # restores (``_digest``)
+            "digest_bytes": 0,
         }
         self.backoff_s = 0.0  # accounted backoff time (never slept)
 
@@ -321,8 +394,15 @@ class HostUnitStore:
         if self.stats is not None:
             setattr(self.stats, name, getattr(self.stats, name) + 1)
 
+    def _digest(self, value, version: int) -> int:
+        """``unit_checksum``, its bytes counted in ``digest_bytes``."""
+        self.wire_stats["digest_bytes"] += sum(
+            int(p.nbytes) for p in _digest_parts(value))
+        return unit_checksum(value, version)
+
     def _wire(self, op: str, field: str, kind: str, idx: int,
-              version: int, host, crc: int):
+              version: int, host, crc: int, *,
+              host_digested: bool = False):
         """One integrity-checked link crossing under the retry policy.
 
         ``host`` is the already-materialized host-side value and
@@ -330,10 +410,17 @@ class HostUnitStore:
         injector (transfer failure / in-flight bit-flip), then
         verifies the received bytes against ``crc`` — corruption is
         *always* detected here, before the payload can be stored or
-        shipped to a stencil step. Failed attempts retry up to
-        ``retry.attempts`` with accounted (never slept) exponential
-        backoff; exhaustion raises ``UnrecoverableFault`` chaining the
-        last failure. Returns the verified value.
+        shipped to a stencil step. ``host_digested`` says ``crc`` was
+        just computed from ``host`` itself (a put): a received copy
+        that *is* ``host`` then holds exactly the digested bytes and
+        is accepted without a second digest, and only a copy the
+        injector made (``corrupt``) is digested. Otherwise (a stage,
+        whose ``crc`` is the one recorded at the put) every attempt
+        re-digests the stored bytes, which catches tampering at rest.
+        Failed attempts retry up to ``retry.attempts`` with accounted
+        (never slept) exponential backoff; exhaustion raises
+        ``UnrecoverableFault`` chaining the last failure. Returns the
+        verified value.
         """
         unit = f"{kind}{idx}"
         attempts = self.retry.attempts if self.retry else 1
@@ -366,14 +453,15 @@ class HostUnitStore:
                     )
                 else:
                     received = FaultInjector.corrupt(host)
-            got = unit_checksum(received, version)
-            if got != crc:
-                self._count("checksum_failures")
-                last = ChecksumError(
-                    f"{op} checksum mismatch for unit {field}.{unit} "
-                    f"v{version}: expected {crc:#010x}, got {got:#010x}"
-                )
-                continue
+            if received is not host or not host_digested:
+                got = self._digest(received, version)
+                if got != crc:
+                    self._count("checksum_failures")
+                    last = ChecksumError(
+                        f"{op} checksum mismatch for unit {field}.{unit} "
+                        f"v{version}: expected {crc:#010x}, got {got:#010x}"
+                    )
+                    continue
             if self.injector is not None and self.injector.straggle(
                 op, field, unit, version
             ) > 1.0:
@@ -404,9 +492,10 @@ class HostUnitStore:
         (deferred writebacks and residency flushes); without it the
         counter bumps by one (the synchronous engine's in-order path).
         Either way the host copy is current afterwards. The D2H
-        crossing is integrity-checked: the checksum computed from the
-        source bytes must match the received copy (injected corruption
-        and transfer failures retry under the store's ``RetryPolicy``).
+        crossing is integrity-checked: the checksum is computed once,
+        from the source bytes, and a received copy that differs from
+        them (injected corruption) must match it (corruption and
+        transfer failures retry under the store's ``RetryPolicy``).
         ``on_wire=False`` marks a host-local put (seeding) that never
         crosses the link — exempt from injection, but still digested.
         ``op`` labels the crossing in the wire log (and for fault
@@ -434,9 +523,10 @@ class HostUnitStore:
                     )
                 else:
                     host = np.asarray(value)
-            crc = unit_checksum(host, version)
+            crc = self._digest(host, version)
             if on_wire:
-                host = self._wire(op, field, kind, idx, version, host, crc)
+                host = self._wire(op, field, kind, idx, version, host, crc,
+                                  host_digested=True)
             # store the payload BEFORE advancing the version maps: a put
             # that fails mid-copy must not leave host_current() true over
             # stale bytes (the flush-retry contract relies on this order).
@@ -571,7 +661,7 @@ class HostUnitStore:
             else:
                 value = np.ascontiguousarray(leaves[ukey])
             ver = int(u["version"])
-            crc = unit_checksum(value, ver)
+            crc = self._digest(value, ver)
             want = u.get("crc32")  # pre-PR 7 snapshots carry none
             if want is not None and int(want) != crc:
                 raise ChecksumError(
@@ -652,7 +742,7 @@ class HostUnitStore:
         with span(spans.STAGE, field=field, unit=f"{kind}{idx}", bytes=wire):
             crc = self._crc.get(key)
             if crc is None:  # pre-digest stores (legacy direct loads)
-                crc = self._crc[key] = unit_checksum(stored, version)
+                crc = self._crc[key] = self._digest(stored, version)
             stored = self._wire(
                 "h2d", field, kind, idx, version, stored, crc
             )

@@ -20,7 +20,13 @@ import pytest
 
 from repro.core import spans
 from repro.core.executor import AsyncExecutor
-from repro.core.outofcore import OOCConfig, paper_code_fields
+from repro.core.outofcore import HostUnitStore, OOCConfig, paper_code_fields
+from repro.distributed.fault import (
+    FaultInjector,
+    FaultPlan,
+    FaultSpec,
+    RetryPolicy,
+)
 from repro.kernels.stencil import ref as stencil_ref
 
 SHAPE = (96, 12, 12)
@@ -142,14 +148,38 @@ def test_one_crossing_span_per_transfer_record(traced):
 
 
 def test_digests_per_crossing(traced):
-    """A put digests its source bytes and then the received copy; a
-    stage digests the received copy once."""
+    """With no fault injected, a put digests its source bytes once (the
+    received copy is the source); a stage digests the stored bytes
+    once."""
     digests = _named(traced, spans.CHECKSUM)
-    for name, want in ((spans.PUT, 2), (spans.STAGE, 1)):
+    for name in (spans.PUT, spans.STAGE):
         for s in _named(traced, name):
             inner = [d for d in digests if d.inside(s)]
-            assert len(inner) == want, name
-            assert sum(d.meta["bytes"] for d in inner) >= s.meta["bytes"]
+            assert len(inner) == 1, name
+            assert inner[0].meta["bytes"] >= s.meta["bytes"]
+
+
+def test_a_corrupted_put_digests_the_received_copy(tmp_path):
+    """Under an injected ``corrupt`` the put digests its source, then
+    the corrupted copy (refused), and accepts the retry, which receives
+    the source again, without a third digest."""
+    cfg = OOCConfig(SHAPE, NDIV, 2, paper_code_fields(1))
+    plan = FaultPlan([FaultSpec(kind="corrupt", op="d2h", attempts=1)])
+    store = HostUnitStore(cfg, injector=FaultInjector(plan),
+                          retry=RetryPolicy(attempts=2))
+    value = np.ones((8, 12, 12), np.float32)
+    with jax.profiler.trace(str(tmp_path)):
+        store.put("p_cur", "R", 0, value)
+    (path,) = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                        recursive=True)
+    got = [Span(e)
+           for plane in jax.profiler.ProfileData.from_file(path).planes
+           for line in plane.lines for e in line.events
+           if e.name.startswith("ooc.")]
+    (put,) = [s for s in got if s.name == spans.PUT]
+    inner = [d for d in got if d.name == spans.CHECKSUM and d.inside(put)]
+    assert [d.meta["bytes"] for d in inner] == [value.nbytes] * 2
+    assert store.wire_stats["checksum_failures"] == 1
 
 
 def test_metadata(traced):
