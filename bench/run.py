@@ -12,25 +12,30 @@ residency budget and the warm-up). Per-layer metrics are read by
 is a new file and a new entry; nothing here changes.
 
 Set-up: the seeded fields are made on the device in one jitted call
-(``reference.fields``), the engine (``AsyncExecutor``) is seeded from
-them through its constructor, and ``warm_rounds`` whole rounds run, so
-that every program and every shape the window uses has been compiled
-(and, for a resident job, the residency is filled). The window then
-runs whole rounds (``advance_round``, the loop ``run`` is built from)
-until ``--seconds`` have passed, drains the engine and waits until the
+(``reference.fields``), the engine is seeded from them through its
+constructor, and ``warm_rounds`` whole rounds run, so that every
+program and every shape the window uses has been compiled (and, for a
+resident job, the residency is filled). The engine is
+``AsyncExecutor``, or, for a job whose file sets ``"shards": N``,
+``ShardedExecutor`` with one shard on each of the cell's N chips. The
+window then runs whole rounds (``advance_round``, the loop ``run`` is
+built from, or the sharded coordinator's ``sweep``) until
+``--seconds`` have passed, drains the engine and waits until the
 device has finished; ``gpts_per_s`` is every point update of the
 window over the whole window.
 
 After the window the engine's fields over a region that straddles a
-block boundary near the pulse (placed from the seed) are compared with
-``reference.run_reference`` over the region's dependency cone, run
-once the engine's device arrays are freed.
+block boundary near the pulse (a shard boundary, where the halo lands,
+for a sharded job; placed from the seed) are read from the shards that
+own them and compared with ``reference.run_reference`` over the
+region's dependency cone, run once the engine's device arrays are
+freed.
 
 With ``--trace 1`` the window runs under the profiler, the benchmark
 wraps the host store's ``stage``/``put`` and the executor's calls of
 the stencil and codec programs in spans (and records each call's
-shapes), and the per-layer metrics are printed instead of the end-to-
-end ones.
+shapes), the engine's own ``ooc.*`` spans are kept beside them, and the
+per-layer metrics are printed instead of the end-to-end ones.
 
 Earlier lines on standard error report what is not a metric; the last
 lines there are the compared numbers beside their limits. The last
@@ -47,10 +52,12 @@ _T_IMPORT = time.time()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
+import numbers  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -125,15 +132,20 @@ def load_reader(metric: str, root: Path = ROOT):
 def check_region(job, seed: int):
     """The checked region, ``((z0, z1), (y0, y1), (x0, x1))``: ``region``
     planes straddling a block boundary in the middle half of the
-    volume, and a ``region``-sized square of Y and X near the centre,
-    both drawn from the seed and on the 4-grid."""
+    volume (for a job with ``shards``, a boundary between two shards,
+    where the halo lands), and a ``region``-sized square of Y and X
+    near the centre, both drawn from the seed and on the 4-grid."""
     import numpy as np
 
     rng = np.random.default_rng((seed, 7))
     (z, y, x), ndiv = job["shape"], job["ndiv"]
     dz, dy, dx = job["region"]
     block = z // ndiv
-    edges = [k * block for k in range(1, ndiv)
+    shards = job.get("shards", 1)
+    # the engine's balanced split: shard d holds blocks from d*ndiv//N
+    cuts = (range(1, ndiv) if shards == 1
+            else [d * ndiv // shards for d in range(1, shards)])
+    edges = [k * block for k in cuts
              if abs(k * block - z / 2) <= z / 4] or [block * (ndiv // 2)]
     edge = int(rng.choice(edges))
     out = [(edge - dz // 2, edge + dz // 2)]
@@ -217,10 +229,72 @@ def wait_device() -> None:
         a.block_until_ready()
 
 
+def shard_devices(job, cell, devices, allow_cpu: bool):
+    """The devices a sharded job's shards are pinned to, one to each of
+    the cell's chips; None for a job without ``shards``, and under
+    ``allow_cpu``, where the shards share the one device. A job whose
+    shards are not the cell's chips is refused."""
+    shards = job.get("shards", 1)
+    if allow_cpu:
+        return None
+    if shards != cell["chips"]:
+        raise Refused(f"the job has {shards} shard(s) and the cell asks "
+                      f"for {cell['chips']} chip(s)")
+    return list(devices[:shards]) if shards > 1 else None
+
+
+def engine_config(config, job):
+    """The engine's ``OOCConfig`` of a configuration and a job."""
+    from repro.core.outofcore import FieldSpec, OOCConfig
+
+    return OOCConfig(
+        tuple(job["shape"]), job["ndiv"], config["bt"],
+        {n: FieldSpec(f["role"], f["planes"])
+         for n, f in config["fields"].items()},
+        backend=config["backend"], dtype=config["dtype"],
+    )
+
+
+def build_engine(cfg, job, p, v, devices):
+    """The engine a job runs on and a call that advances it one round:
+    ``AsyncExecutor`` and its ``advance_round``, or, for a job with
+    ``shards`` over 1, ``ShardedExecutor`` (``cache_bytes`` per device)
+    and its ``sweep``."""
+    kw = {"schedule": job["schedule"], "cache_bytes": job["cache_bytes"]}
+    shards = job.get("shards", 1)
+    if shards > 1:
+        from repro.core.sharded import ShardedExecutor
+
+        eng = ShardedExecutor(cfg, p, p, v, nshards=shards, devices=devices,
+                              **kw)
+        return eng, eng.sweep
+    from repro.core.executor import AsyncExecutor
+
+    eng = AsyncExecutor(cfg, p, p, v, **kw)
+    return eng, functools.partial(eng.advance_round, FAR)
+
+
+def owners(eng):
+    """``[(store, device, units)]``: each host store with the units
+    ``(kind, idx, (lo, hi))`` it holds the committed values of, and the
+    device its engine runs on. A sharded engine's units are read from
+    the shard that owns them, never from a neighbour's ghost mirror."""
+    units = eng.plan.units()
+    if not hasattr(eng, "specs"):
+        return [(eng.store, None, units)]
+    out = []
+    for spec, ex in zip(eng.specs, eng.shards):
+        owned = set(spec.owned_units())
+        out.append((ex.store, spec.device,
+                    [u for u in units if u[:2] in owned]))
+    return out
+
+
 def read_region(eng, region):
-    """The engine's p_prev and p_cur over ``region``, from its host
-    store after a flush, compressed units decoded by the engine's own
-    decoder."""
+    """The engine's p_prev and p_cur over ``region``, from the host
+    store of each unit's owner after a flush, compressed units decoded
+    by the engine's own decoder on the owner's device."""
+    import jax
     import numpy as np
 
     from repro.kernels.zfp import ops as zfp_ops
@@ -232,17 +306,30 @@ def read_region(eng, region):
     out = {}
     for name in ("p_prev", "p_cur"):
         got = np.empty((z1 - z0, y1 - y0, x1 - x0), np.float32)
-        for kind, idx, (lo, hi) in eng.plan.units():
-            a, b = max(lo, z0), min(hi, z1)
-            if a >= b:
-                continue
-            unit = eng.store.get(name, kind, idx)
-            if isinstance(unit, Compressed):
-                unit = zfp_ops.decompress(unit, backend=eng.cfg.backend)
-            got[a - z0 : b - z0] = np.asarray(unit)[a - lo : b - lo,
-                                                    y0:y1, x0:x1]
+        for store, device, units in owners(eng):
+            for kind, idx, (lo, hi) in units:
+                a, b = max(lo, z0), min(hi, z1)
+                if a >= b:
+                    continue
+                unit = store.get(name, kind, idx)
+                if isinstance(unit, Compressed):
+                    with (jax.default_device(device) if device is not None
+                          else contextlib.nullcontext()):
+                        unit = zfp_ops.decompress(unit,
+                                                  backend=eng.cfg.backend)
+                got[a - z0 : b - z0] = np.asarray(unit)[a - lo : b - lo,
+                                                        y0:y1, x0:x1]
         out[name] = got
     return out
+
+
+def residency(eng) -> Dict[str, int]:
+    """Residency hits, misses and elided D2H since seeding, summed over
+    the shards of a sharded engine."""
+    stats = eng.stats()
+    per = stats["per_device"].values() if "per_device" in stats else [stats]
+    return {k: sum(s["cache"][k] for s in per)
+            for k in ("hits", "misses", "d2h_elided")}
 
 
 def run_cell(loaded, seed: int, seconds: float, trace: bool,
@@ -273,11 +360,11 @@ def run_cell(loaded, seed: int, seconds: float, trace: bool,
         raise Refused(f"no peaks for device kind {dev.device_kind!r} in "
                       "bench/peaks.json")
 
-    from repro.core.executor import AsyncExecutor
-    from repro.core.outofcore import FieldSpec, OOCConfig
-
     from bench import reference
+    from bench import spans
     from bench import traces as tr
+
+    pinned = shard_devices(job, cell, devices, allow_cpu)
 
     # the compile cache lives at a fixed path in the checkout, every
     # program in it, and the program takes the same directory
@@ -298,24 +385,18 @@ def run_cell(loaded, seed: int, seconds: float, trace: bool,
 
     # ---- set-up -----------------------------------------------------
     shape = tuple(job["shape"])
-    cfg = OOCConfig(
-        shape, job["ndiv"], config["bt"],
-        {n: FieldSpec(f["role"], f["planes"])
-         for n, f in config["fields"].items()},
-        backend=config["backend"], dtype=config["dtype"],
-    )
+    cfg = engine_config(config, job)
     t = time.time()
     p, v = reference.fields(shape, seed)
     p, v = np.asarray(p), np.asarray(v)
     t_fields = time.time() - t
     t = time.time()
-    eng = AsyncExecutor(cfg, p, p, v, schedule=job["schedule"],
-                        cache_bytes=job["cache_bytes"])
+    eng, advance = build_engine(cfg, job, p, v, pinned)
     del p, v
     t_seed = time.time() - t
     t = time.time()
     for _ in range(job["warm_rounds"]):
-        eng.advance_round(FAR)
+        advance()
     eng.finish()
     wait_device()
     log(f"set-up parts: fields to host {t_fields!r} s, seeding "
@@ -337,7 +418,7 @@ def run_cell(loaded, seed: int, seconds: float, trace: bool,
         with jax.profiler.TraceAnnotation(tr.WINDOW_SPAN):
             t0 = time.time()
             while True:
-                eng.advance_round(FAR)
+                advance()
                 rounds += 1
                 if time.time() - t0 >= seconds:
                     break
@@ -348,19 +429,24 @@ def run_cell(loaded, seed: int, seconds: float, trace: bool,
     window_s = t1 - t0
     steps = (eng.sweeps_done - sweeps0) * config["bt"]
     after = eng.transfer_summary()
-    moved = {k: after[k] - before.get(k, 0) for k in after}
-    peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-               for d in devices[: cell["chips"]])
+    moved = {k: n - before.get(k, 0) for k, n in after.items()
+             if isinstance(n, numbers.Number)}
+    chip_peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                  for d in devices[: cell["chips"]]]
+    peak = max(chip_peaks)
     points = math.prod(shape)
     log(f"window: {rounds} rounds, {steps} steps of {points} points in "
         f"{window_s!r} s; compilations in the window: {compiles['window']}")
-    cache_stats = eng.stats()["cache"]
+    cache_stats = residency(eng)
     log(f"wire bytes in the window: h2d {moved['h2d_wire']} (raw "
         f"{moved['h2d_raw']}), d2h {moved['d2h_wire']} (raw "
-        f"{moved['d2h_raw']}); residency since seeding: hits "
+        f"{moved['d2h_raw']}), halo {moved['halo_wire']} (raw "
+        f"{moved['halo_raw']}, {moved['halo_count']} crossings); "
+        f"residency since seeding: hits "
         f"{cache_stats['hits']}, misses {cache_stats['misses']}, d2h "
         f"elided {cache_stats['d2h_elided']}")
-    log(f"set-up {setup_s!r} s; peak_bytes_in_use {peak}")
+    log(f"set-up {setup_s!r} s; peak_bytes_in_use {peak} (each chip: "
+        f"{chip_peaks})")
 
     # ---- the check --------------------------------------------------
     region = check_region(job, seed)
@@ -405,7 +491,7 @@ def run_cell(loaded, seed: int, seconds: float, trace: bool,
             device["busy_s"] = got_busy[0] / 1e9
             device["window_s"] = got_busy[1] / 1e9
         result["breakdown"] = {"device_ops": tr.top_ops(traced),
-                               "idle_gaps": tr.idle_gaps(traced)}
+                               "idle_gaps": spans.idle_gaps(traced)}
     result.update(metrics=metrics, device=device, checks=checks)
     for name, c in checks.items():
         log(f"{name}: {c['value']!r} (limit {c['limit']!r})")

@@ -5,6 +5,7 @@ records, on traces recorded on the chip, and in whole CPU runs."""
 import copy
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
@@ -168,10 +169,21 @@ def test_the_new_readers_read_nothing_from_a_trace_without_them(metric):
     assert load_reader(metric)(copy.deepcopy(OLD["record"])) is None
 
 
+# the gaps of the old trace as the largest host-span overlap named them
+# before gaps were named by the innermost span
+OLD_GAPS = [
+    ["bench.store.put", 6.993854175], ["bench.store.put", 5.589175257],
+    ["bench.store.put", 5.569577092], ["bench.store.put", 5.344848379],
+    ["bench.store.put", 5.319062337], ["bench.store.put", 5.249556038],
+    ["bench.store.put", 5.237724221], ["bench.store.stage", 2.05302446],
+    ["bench.store.stage", 1.844659628], ["bench.window", 0.011796869],
+]
+
+
 def test_the_old_trace_s_gaps_keep_their_names():
     """A trace with no program spans names its gaps as before."""
     trace = OLD["record"]["trace"]
-    assert spans.idle_gaps(trace) == traces.idle_gaps(trace)
+    assert spans.idle_gaps(trace) == OLD_GAPS
     assert spans.idle_gaps(trace)[0][0] == "bench.store.put"
 
 
@@ -195,9 +207,11 @@ def test_the_engine_s_store_spans_match_the_harness_s_wrappers():
 
 
 def test_the_stream_window_s_longest_gaps_are_digests():
+    """Named by the innermost span; the largest overlap named the same
+    gaps ``ooc.finish`` and ``ooc.round``, which enclose the digests."""
     gaps = spans.idle_gaps(SPANS["record"]["trace"])
     assert [g[0] for g in gaps[:9]] == ["ooc.store.checksum"] * 9
-    assert traces.idle_gaps(SPANS["record"]["trace"])[0][0] != gaps[0][0]
+    assert gaps[0] == ["ooc.store.checksum", 7.118841988]
 
 
 # ---------------------------------------------------------------------------
@@ -208,8 +222,7 @@ SEED = 2**31 + 13
 
 
 @pytest.mark.parametrize("cell", ["code4.stream-1152", "code1.resident-384"])
-def test_a_traced_run_reads_the_engine_s_spans(cell, tiny, monkeypatch):
-    monkeypatch.setattr(traces, "SPAN_PREFIX", ("bench.", "ooc."))
+def test_a_traced_run_reads_the_engine_s_spans(cell, tiny):
     loaded = tiny(cell)
     stream = cell.startswith("code4.")
     mine = NEW if stream else ["dispatch_ms_per_step"]
@@ -220,12 +233,42 @@ def test_a_traced_run_reads_the_engine_s_spans(cell, tiny, monkeypatch):
         assert got["metrics"][m]["value"] > 0, m
 
 
-def test_the_harness_as_it_stands_keeps_no_engine_span(tiny):
-    """Until ``bench/traces.py`` keeps ``ooc.*`` host events, the new
-    readers read nothing and a traced run reports only the old
-    metrics."""
+def test_the_cell_as_committed_reports_the_engine_s_spans(tiny):
+    """``BENCHMARK.json`` lists the four readers for the stream cell,
+    and ``bench/traces.py`` keeps the ``ooc.*`` host events they read:
+    a traced run reports each."""
     loaded = tiny("code4.stream-1152")
-    loaded["per_layer"] += [{"name": m, "unit": "ms/step"} for m in NEW]
+    assert set(NEW) <= {m["name"] for m in loaded["per_layer"]}
     got = run.run_cell(loaded, SEED, 0.05, True, allow_cpu=True)
     assert got["correct"], got["checks"]
-    assert not set(NEW) & set(got["metrics"])
+    for m in NEW:
+        assert got["metrics"][m]["value"] > 0, m
+
+
+def test_the_resident_cell_reports_no_dispatch():
+    """There the host waits inside ``ooc.stencil``; the reader would
+    read the device-paced step, which ``gpts_per_s`` already shows."""
+    names = {m["name"] for m in run.load_cell("code1.resident-384")[
+        "per_layer"]}
+    assert not set(NEW) & names
+
+
+def _plane(name, line, *events):
+    """A profiler plane of one line, as ``ProfileData`` gives it."""
+    return SimpleNamespace(name=name, lines=[SimpleNamespace(
+        name=line, events=[SimpleNamespace(name=n, start_ns=s, duration_ns=d)
+                           for n, s, d in events])])
+
+
+def test_reduce_xspace_keeps_bench_and_ooc_host_events():
+    data = SimpleNamespace(planes=[
+        _plane("/host:CPU", "python3", ("bench.window", 0, 100),
+               ("ooc.store.d2h", 10, 5), ("jit_compress", 20, 5),
+               ("PjitFunction", 30, 1), ("oocx", 40, 1)),
+        _plane("/device:TPU:0", "XLA Modules", ("jit_compress(1)", 21, 3)),
+    ])
+    got = traces.reduce_xspace(data)
+    assert got["host"] == [["bench.window", 0, 100],
+                           ["ooc.store.d2h", 10, 5]]
+    assert got["device"] == {
+        "/device:TPU:0": {"XLA Modules": [["jit_compress(1)", 21, 3]]}}

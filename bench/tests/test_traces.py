@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import traces
+from bench import spans, traces
 from bench.run import load_reader
 
 DATA = json.loads(
@@ -40,7 +40,7 @@ def test_shares_never_pass_100(record):
 
 def test_breakdown(record):
     ops = traces.top_ops(record["trace"])
-    gaps = traces.idle_gaps(record["trace"])
+    gaps = spans.idle_gaps(record["trace"])
     assert 0 < len(ops) <= 10 and 0 < len(gaps) <= 10
     assert ops == sorted(ops, key=lambda o: -o[1])
     assert gaps == sorted(gaps, key=lambda g: -g[1])

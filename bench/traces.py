@@ -4,7 +4,8 @@ readers share.
 ``capture`` runs a block under the JAX profiler and returns the trace
 reduced to a compact record: every line of every device plane (the
 TPU's ``XLA Modules`` and ``XLA Ops`` lines among them) and the host
-spans whose names start with ``bench.``, each event as
+spans whose names start with ``bench.`` (the harness's) or ``ooc.``
+(the engine's own), each event as
 ``[name, start_ns, duration_ns]`` on the profiler's one clock. The
 trace file itself lives in a temporary directory that is removed once
 it has been read.
@@ -19,7 +20,7 @@ import shutil
 import tempfile
 from typing import Dict, Iterable, List, Optional, Tuple
 
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = ("bench.", "ooc.")
 WINDOW_SPAN = "bench.window"
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
@@ -156,31 +157,3 @@ def top_ops(trace, k: int = 10) -> List[list]:
                 tot[name] = tot.get(name, 0.0) + dur
     best = sorted(tot.items(), key=lambda kv: -kv[1])[:k]
     return [[name[:120], ns / 1e9] for name, ns in best]
-
-
-def idle_gaps(trace, k: int = 10) -> List[list]:
-    """The ``k`` longest idle stretches of the first chip inside the
-    window, each named by the host span that covers most of it (the
-    span ``bench.window`` itself stands for host work outside every
-    other span)."""
-    got = busy(trace)
-    if got is None:
-        return []
-    _, _, per_chip = got
-    lo, hi = window(trace)
-    edges = [lo] + [x for iv in per_chip[0] for x in iv] + [hi]
-    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges) - 1, 2)
-            if edges[i + 1] > edges[i]]
-    gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:k]
-    spans = [(n, s, s + d) for n, s, d in trace.get("host", ())
-             if n != WINDOW_SPAN]
-    out = []
-    for a, b in gaps:
-        cover: Dict[str, float] = {}
-        for n, s, e in spans:
-            ov = min(b, e) - max(a, s)
-            if ov > 0:
-                cover[n] = cover.get(n, 0.0) + ov
-        name = max(cover, key=cover.get) if cover else WINDOW_SPAN
-        out.append([name, (b - a) / 1e9])
-    return out
