@@ -25,11 +25,14 @@ Per round (``kr`` fused sweeps), shards run ascending:
    integrity-checked, versioned ``+kr``, retried under the same
    policies as every other crossing, and wire-logged as op ``"halo"``.
 
-Both flows are recorded as ``Transfer("halo", ...)`` on the *exporting*
-shard, so per-device transfer logs compare one-to-one against the
-per-shard task graphs (``build_sweep_tasks(shard=...)``) and the merged
-replay (``build_sharded_tasks`` / ``pipeline.sharded_timeline``) —
-model and live agree on the full transfer multiset including halos.
+Each shard's sweep is an ``ooc.shard`` span, and each crossing of
+either flow one ``ooc.halo.held`` or ``ooc.halo.unit`` span carrying
+its wire bytes (``repro.core.spans``). Both flows are recorded as
+``Transfer("halo", ...)`` on the *exporting* shard, so per-device
+transfer logs compare one-to-one against the per-shard task graphs
+(``build_sweep_tasks(shard=...)``) and the merged replay
+(``build_sharded_tasks`` / ``pipeline.sharded_timeline``) — model and
+live agree on the full transfer multiset including halos.
 
 Numerics are **bit-identical** to the single-device engine: the ghost
 fetch decodes the exact unit the neighbor committed, the held import is
@@ -61,11 +64,14 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import numpy as np
 
+from repro.core import spans
 from repro.core.executor import (
     AsyncExecutor,
+    _payload_nbytes,
     _payload_raw_bytes,
 )
 from repro.core.outofcore import OOCConfig
+from repro.core.spans import span
 from repro.core.taskgraph import (
     Schedule,
     Transfer,
@@ -185,34 +191,37 @@ class ShardedExecutor:
         in-flight window persists across rounds; no global drain."""
         kr = self.temporal if sweeps is None else sweeps
         s0 = self.sweeps_done
-        held: Dict[str, jax.Array] = {}
         for d, ex in enumerate(self.shards):
             spec = self.specs[d]
-            if d > 0:
-                for name, val in held.items():
-                    ex.deliver_held(name, val)
-            with self._on(spec):
+            with self._on(spec), span(
+                spans.SHARD, shard=d, round=self.rounds_done,
+            ):
                 ex.sweep(kr)
             self.monitor.beat(d, self.rounds_done, self._timer())
-            held = ex.take_held()
-            for name, val in held.items():
+            # the last shard exports no held slices
+            for name, val in ex.take_held().items():
                 nb = int(val.size) * val.dtype.itemsize
-                self._log_halo(
-                    ex, name, ("C", spec.block_hi - 1), nb, nb, s0,
-                    spec.block_hi - 1,
-                )
+                with span(spans.HALO_HELD, shard=d, field=name, bytes=nb):
+                    self.shards[d + 1].deliver_held(name, val)
+                    self._log_halo(
+                        ex, name, ("C", spec.block_hi - 1), nb, nb, s0,
+                        spec.block_hi - 1,
+                    )
         for d in range(1, self.nshards):
             ex = self.shards[d]
             spec = self.specs[d]
             for (field, unit), (val, ver) in ex.take_halo().items():
-                with self._on(self.specs[d - 1]):
+                with self._on(self.specs[d - 1]), span(
+                    spans.HALO_UNIT, shard=d, field=field,
+                    unit=f"{unit[0]}{unit[1]}", bytes=_payload_nbytes(val),
+                ):
                     wire = self.shards[d - 1].deliver_halo(
                         field, unit[0], unit[1], val, ver,
                     )
-                self._log_halo(
-                    ex, field, unit, _payload_raw_bytes(val), wire,
-                    s0, spec.block_lo,
-                )
+                    self._log_halo(
+                        ex, field, unit, _payload_raw_bytes(val), wire,
+                        s0, spec.block_lo,
+                    )
         now = self._timer()
         stragglers = self.monitor.stragglers(now)
         if stragglers:

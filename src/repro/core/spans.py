@@ -30,6 +30,12 @@ D2H = "ooc.store.d2h"            # put materializing it on the host
 H2D = "ooc.store.h2d"            # stage handing the host bytes to JAX
 CHECKSUM = "ooc.store.checksum"  # one crc32 digest: bytes
 
+# shard coordinator (core/sharded.py, ShardedExecutor); on a halo
+# span ``shard`` is the exporter, whose transfer log records the crossing
+SHARD = "ooc.shard"          # one shard's sweep of a round: shard, round
+HALO_HELD = "ooc.halo.held"  # held slice, left to right: shard, field, bytes
+HALO_UNIT = "ooc.halo.unit"  # unit halo put, right to left: shard, field, unit, bytes
+
 
 def span(name: str, **meta):
     """The host span ``name``, carrying ``meta`` as event stats."""
