@@ -16,8 +16,12 @@ task graph (``repro.core.taskgraph.build_sweep_tasks``) for real:
   dispatched (decompression through the batched ``decompress_units``
   burst), so the device queue runs ahead of the host;
 * every ``d2h`` task is *deferred*: the computed (or encoded) unit is
-  parked in the in-flight window and only materialized to host memory
-  (``np.asarray``, the actual D2H) when the window must drain.
+  parked in the in-flight window and committed when the window must
+  drain. A writeback that will pay a host copy (no residency,
+  write-through, or a refused write-back deposit) has that copy
+  started as it is parked (``start_host_copy``), so the D2H runs on
+  the link while the next visit computes; the drain's put collects
+  the landed copy (``np.asarray``).
 
 The window is bounded — at most ``depth`` block visits may hold pending
 writebacks at once (default 2, i.e. double buffering) — and it stays
@@ -103,7 +107,7 @@ import statistics
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Deque, Dict, List, Optional, Tuple, Union
+from typing import Deque, Dict, List, Optional, Set, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -111,7 +115,12 @@ import numpy as np
 
 from repro.checkpoint import checkpoint as ckpt
 from repro.core import spans
-from repro.core.outofcore import HostUnitStore, OOCConfig, unit_shards
+from repro.core.outofcore import (
+    HostUnitStore,
+    OOCConfig,
+    start_host_copy,
+    unit_shards,
+)
 from repro.core.ratecontrol import RateController, rate_label
 from repro.core.spans import span
 from repro.core.taskgraph import (
@@ -141,8 +150,10 @@ CKPT_FORMAT = 1
 
 UnitKey = Tuple[str, Tuple[str, int]]  # (field, (kind, idx))
 
-# one parked visit: (producing sweep, [(task, value, raw, version)])
-_Parked = Tuple[int, List[Tuple[Task, object, int, int]]]
+# one parked writeback: (task, value, raw, version, whether its host
+# copy was started at park); one parked visit: (producing sweep, [...])
+_Writeback = Tuple[Task, object, int, int, bool]
+_Parked = Tuple[int, List[_Writeback]]
 
 
 @dataclass
@@ -511,6 +522,7 @@ class AsyncExecutor:
         link crossing. Returns wire bytes."""
         wire = self.store.put(
             field, kind, idx, value, version=version, op="halo",
+            early=True,  # the exporter started it at park
         )
         self._ver[(field, (kind, idx))] = version
         return wire
@@ -533,7 +545,7 @@ class AsyncExecutor:
         if not parked:  # every writeback of the visit committed early
             return
         with span(spans.DRAIN, round=sweep_no, block=parked[0][0].block):
-            for task, value, raw, ver in parked:
+            for task, value, raw, ver, early in parked:
                 kind, idx = task.unit
                 if self.cache.enabled and self.cache.write_back:
                     if self.store.version_of(task.field, kind, idx) >= ver:
@@ -543,7 +555,7 @@ class AsyncExecutor:
                         self.store.commit_device(task.field, kind, idx, ver)
                         continue
                 wire = self.store.put(
-                    task.field, kind, idx, value, version=ver
+                    task.field, kind, idx, value, version=ver, early=early
                 )
                 self.transfers.append(Transfer(
                     "d2h", task.field, task.unit, raw, wire,
@@ -770,20 +782,25 @@ class AsyncExecutor:
             wire, self.sweeps_done, block, flush=True, reissued=reissued,
         ))
 
-    def _park_writebacks(self, btasks: List[Task], kr: int = 1) -> None:
+    def _park_writebacks(
+        self, btasks: List[Task], kr: int = 1
+    ) -> Set[UnitKey]:
         """Bump unit versions (by ``kr`` — one fused visit advances a
         unit ``kr`` sweeps), deposit the on-device payloads into
         residency (dirty under write-back, so the d2h can commit
         without a host copy; the next sweep can hit either way), and
         park the d2h tasks in the window. Dirty LRU victims of the
-        deposits flush here — the eviction point."""
-        parked: List[Tuple[Task, object, int, int]] = []
+        deposits flush here — the eviction point. A writeback that will
+        pay its put at drain has its host copy started here; returns the
+        keys of those."""
+        parked: List[_Writeback] = []
         for t in (t for t in btasks if t.kind == "d2h"):
             key = (t.field, t.unit)
             val = self._outvals.pop(key)
             raw = self._outraw.pop(key)
             ver = self._ver.get(key, 0) + kr
             self._ver[key] = ver
+            pays = True
             if self.cache.enabled:
                 nbytes = _payload_nbytes(val)
                 res = self.cache.deposit(
@@ -804,10 +821,17 @@ class AsyncExecutor:
                     # its own D2H. Account the elision now, in
                     # lockstep with the graph builder.
                     self.cache.note_d2h_elided(nbytes)
-            parked.append((t, val, raw, ver))
+                    pays = False
+            if pays:
+                # no residency, write-through, or a refused deposit:
+                # the drain puts it, so its D2H starts now and lands
+                # while the next visit computes
+                start_host_copy(val)
+            parked.append((t, val, raw, ver, pays))
         if parked:
             self._pending.append((self.sweeps_done, parked))
         self.max_inflight = max(self.max_inflight, len(self._pending))
+        return {(t.field, t.unit) for t, _, _, _, pays in parked if pays}
 
     # ------------------------------------------------------------------
     # sweep loop
@@ -864,14 +888,19 @@ class AsyncExecutor:
                     # pops the payload: the halo ships the same encoded
                     # object the writeback commits, at the version the
                     # park will issue
-                    for t in btasks:
-                        if t.kind == "halo" and ".halo." in t.tid:
-                            key = (t.field, t.unit)
-                            self._halo_out[key] = (
-                                self._outvals[key],
-                                self._ver.get(key, 0) + kr,
-                            )
-                    self._park_writebacks(btasks, kr)
+                    halo = [(t.field, t.unit) for t in btasks
+                            if t.kind == "halo" and ".halo." in t.tid]
+                    for key in halo:
+                        self._halo_out[key] = (
+                            self._outvals[key],
+                            self._ver.get(key, 0) + kr,
+                        )
+                    started = self._park_writebacks(btasks, kr)
+                    # deliver_halo puts every export whatever residency
+                    # decided: start the copies the park did not
+                    for key in halo:
+                        if key not in started:
+                            start_host_copy(self._halo_out[key][0])
             if self.shard is not None and not self.shard.last:
                 last = self._blocks[-1]
                 self._held_out = {n: held[n + str(last)] for n in rw}
@@ -1180,8 +1209,8 @@ class AsyncExecutor:
         host bytes. The window stays parked: visits keep overlapping.
         """
         for i, (sweep_no, parked) in enumerate(self._pending):
-            kept: List[Tuple[Task, object, int, int]] = []
-            for task, value, raw, ver in parked:
+            kept: List[_Writeback] = []
+            for task, value, raw, ver, early in parked:
                 kind, idx = task.unit
                 key = (task.field, task.unit)
                 if self.store.version_of(task.field, kind, idx) >= ver:
@@ -1189,10 +1218,10 @@ class AsyncExecutor:
                 if self.cache.enabled and self.cache.write_back:
                     ent = self.cache.peek(key)
                     if ent is not None and ent.dirty and ent.version >= ver:
-                        kept.append((task, value, raw, ver))
+                        kept.append((task, value, raw, ver, early))
                         continue  # snapshot pins the dirty resident
                 wire = self.store.put(
-                    task.field, kind, idx, value, version=ver
+                    task.field, kind, idx, value, version=ver, early=early
                 )
                 self.transfers.append(Transfer(
                     "d2h", task.field, task.unit, raw, wire,

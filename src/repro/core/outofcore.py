@@ -263,11 +263,23 @@ def _digest_parts(value) -> tuple:
     return (value,)
 
 
+def start_host_copy(value) -> None:
+    """Start the device-to-host copy of a device payload (a raw array,
+    or both leaves of a ``Compressed``) without waiting for it. JAX
+    keeps the landed copy on the array, so a later ``np.asarray``
+    returns it and waits only for what has not landed yet."""
+    leaves = _digest_parts(value)
+    with span(spans.D2H_START, bytes=sum(int(x.nbytes) for x in leaves)):
+        for leaf in leaves:
+            leaf.copy_to_host_async()
+
+
 def unit_checksum(value, version: int) -> int:
     """crc32 integrity digest of one unit: payload (+emax for
     compressed units) chained with the version it realizes, so a stale
     payload can never pass as a newer one. Computed from *host* bytes
-    (for device values ``np.asarray`` is the materialization — callers
+    (for device values ``np.asarray`` is the materialization, and may
+    return a copy that ``start_host_copy`` already landed — callers
     on hot paths pass the already-materialized copy), read in place;
     a part over ``DIGEST_SPLIT`` bytes is digested in chunks on
     several threads, to the same value."""
@@ -383,6 +395,9 @@ class HostUnitStore:
             # bytes read by the digests of puts, crossings and
             # restores (``_digest``)
             "digest_bytes": 0,
+            # puts whose value's host copy was started before the put
+            # was reached (``start_host_copy``)
+            "d2h_early": 0,
         }
         self.backoff_s = 0.0  # accounted backoff time (never slept)
 
@@ -485,6 +500,7 @@ class HostUnitStore:
         version: Optional[int] = None,
         on_wire: bool = True,
         op: str = "d2h",
+        early: bool = False,
     ) -> int:
         """Store; returns wire bytes (what crossed the link).
 
@@ -502,6 +518,14 @@ class HostUnitStore:
         injection): ``"d2h"`` for the device->host link, ``"halo"``
         for an inter-device halo put landing in a neighbor shard's
         ghost mirror.
+
+        The put waits for the value, materializes it (``np.asarray``),
+        digests it and crosses the wire. ``early`` says that the caller
+        started the value's host copy before the put
+        (``start_host_copy``): ``np.asarray`` then returns that copy,
+        already landed or landing, and waits only for the part still in
+        flight; such a put counts in ``wire_stats["d2h_early"]`` and its
+        span says ``early``.
         """
         key = (field, kind, idx)
         if version is None:
@@ -510,10 +534,11 @@ class HostUnitStore:
         compressed = isinstance(value, Compressed)
         wire = int(value.nbytes() if compressed else value.nbytes)
         with span(spans.PUT, field=field, unit=f"{kind}{idx}", bytes=wire,
-                  op=op):
+                  op=op, early=early):
             with span(spans.WAIT):
                 jax.block_until_ready(value)
-            # materialize once — for device values this is the D2H
+            # materialize once — for device values this is the D2H, or
+            # the collection of a copy started at park
             with span(spans.D2H):
                 if compressed:
                     host: object = Compressed(
@@ -535,6 +560,7 @@ class HostUnitStore:
             self._crc[key] = crc
             self._versions[key] = max(version, self._versions.get(key, 0))
             self._host_versions[key] = version
+        self.wire_stats["d2h_early"] += early
         return wire
 
     def get(self, field: str, kind: str, idx: int):

@@ -24,9 +24,10 @@ FLUSH = "ooc.flush"
 
 # host store and link (core/outofcore.py, HostUnitStore)
 STAGE = "ooc.store.stage"        # one h2d crossing: field, unit, bytes
-PUT = "ooc.store.put"            # one put: field, unit, bytes, op
+PUT = "ooc.store.put"            # one put: field, unit, bytes, op, early
 WAIT = "ooc.store.wait"          # put waiting for the device value
 D2H = "ooc.store.d2h"            # put materializing it on the host
+D2H_START = "ooc.store.d2h.start"  # a host copy started ahead: bytes
 H2D = "ooc.store.h2d"            # stage handing the host bytes to JAX
 CHECKSUM = "ooc.store.checksum"  # one crc32 digest: bytes
 

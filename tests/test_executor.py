@@ -453,6 +453,65 @@ def test_writeback_version_commits_without_host_copy():
     assert live.store.host_version_of("p_prev", "R", 1) == 3
 
 
+# ----------------------------------------------------------------------
+# host copies started at park
+# ----------------------------------------------------------------------
+
+# case -> (policy, cache budget, whether the drain puts each writeback)
+HOST_COPY_CASES = {
+    "no-residency": ("write-back", 0, True),
+    "write-through": ("write-through", 1 << 30, True),
+    "refused-deposit": ("write-back", 1, True),  # under one unit
+    "resident": ("write-back", 1 << 30, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HOST_COPY_CASES))
+def test_host_copy_starts_at_park_only_for_writebacks_that_pay(
+    case, host_copies
+):
+    """A writeback that the drain will put has its D2H started as it is
+    parked, once, and every such put reports ``early`` and counts in
+    ``d2h_early``; a write-back deposit that was stored commits on the
+    device and starts no copy. Either way the output is bit-identical
+    to the synchronous engine."""
+    policy, budget, pays = HOST_COPY_CASES[case]
+    p_prev, p_cur, vel2 = _initial(SHAPE)
+    cfg = OOCConfig(SHAPE, 4, BT, paper_code_fields(4))
+    sync = OutOfCoreWave(cfg, p_prev, p_cur, vel2)
+    live = AsyncExecutor(cfg, p_prev, p_cur, vel2, cache_bytes=budget,
+                         policy=policy)
+    live.sweep()  # the warm round
+    live.finish()
+    n0, early0 = len(live.transfers), live.store.wire_stats["d2h_early"]
+    started, puts = host_copies
+    started.clear(), puts.clear()
+    for _ in range(2):
+        live.sweep()
+    live.finish()
+    drained = [t for t in live.transfers[n0:] if t.direction == "d2h"]
+    assert not any(t.flush for t in drained)
+    early = live.store.wire_stats["d2h_early"] - early0
+    if pays:
+        assert drained and len(started) == len(drained)
+        assert len({id(v) for v in started}) == len(started)
+        assert len(puts) == len(drained)
+        assert all(m["early"] for m in puts)
+        assert early == len(drained)
+    else:
+        assert not started and not puts and not drained
+        assert early == 0
+    sync.run(3 * BT)
+    for name in ("p_cur", "p_prev"):
+        np.testing.assert_array_equal(
+            live.gather(name), sync.gather(name)
+        )
+    if not pays:  # gather's flush puts values it never started
+        assert live.store.wire_stats["d2h_early"] == 0
+    assert live.stats()["wire"]["d2h_early"] == (
+        live.store.wire_stats["d2h_early"])
+
+
 def test_get_schedule_parsing():
     assert get_schedule("paper").codec_sync
     assert get_schedule("unitgrain").window is None

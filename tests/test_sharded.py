@@ -92,6 +92,37 @@ def test_four_shards_one_block_each():
     assert np.array_equal(ref.gather("p_prev"), sh.gather("p_prev"))
 
 
+@pytest.mark.parametrize("budget", [0, 1 << 30])
+def test_halo_puts_collect_copies_started_at_park(budget, host_copies):
+    """Every put of the rounds, unit halos included, collects a host
+    copy started when its visit was parked: without residency the
+    halo's copy is its writeback's own; with the writebacks committed
+    on the device, the halo starts one. No array is started twice, and
+    the bits are one device's."""
+    p_prev, p_cur, vel2 = _initial(SHAPE, seed=4)
+    ref = AsyncExecutor(_cfg(code=4), p_prev, p_cur, vel2,
+                        schedule="depth2", cache_bytes=budget)
+    ref.run(3 * 2)
+    sh = ShardedExecutor(
+        _cfg(code=4), p_prev, p_cur, vel2, nshards=4,
+        schedule="depth2", cache_bytes=budget, devices=_devices(4),
+    )
+    started, puts = host_copies
+    started.clear(), puts.clear()
+    sh.run_sweeps(3)
+    sh.finish()
+    halo = [m for m in puts if m["op"] == "halo"]
+    assert halo and all(m["early"] for m in puts)
+    assert len(halo) == 3 * (4 - 1) * 2  # rounds x boundaries x rw fields
+    if budget:  # the drains commit on the device: only halos start
+        assert len(started) == len(puts) == len(halo)
+    assert len({id(v) for v in started}) == len(started)
+    assert sum(st["wire"]["d2h_early"]
+               for st in sh.stats()["per_device"].values()) == len(puts)
+    for name in ("p_prev", "p_cur"):
+        assert np.array_equal(ref.gather(name), sh.gather(name)), name
+
+
 @pytest.mark.skipif(
     len(jax.devices()) < 2, reason="needs >= 2 JAX devices"
 )

@@ -46,7 +46,8 @@ EVERY = {v for k, v in vars(spans).items() if k.isupper()} - SHARDED
 NSHARDS = 4  # one 24-plane block a shard: every boundary is a shard's
 # what the resident run never does after its warm round: no unit
 # crosses host -> device, and no writeback is put before the flush
-RESIDENT_ABSENT = {spans.STAGE, spans.H2D}
+# and no copy is started ahead of a put
+RESIDENT_ABSENT = {spans.STAGE, spans.H2D, spans.D2H_START}
 
 
 class Span:
@@ -132,6 +133,7 @@ def test_spans_nest_as_the_engine_calls_them(traced):
         assert _within(s, stores), "a digest outside every crossing"
     for name, parent in ((spans.WAIT, spans.PUT), (spans.D2H, spans.PUT),
                          (spans.H2D, spans.STAGE),
+                         (spans.D2H_START, spans.VISIT),
                          (spans.VISIT, spans.ROUND)):
         parents = _named(traced, parent)
         for s in _named(traced, name):
@@ -164,6 +166,21 @@ def test_one_crossing_span_per_transfer_record(traced):
     for op, got in (("h2d", stages), ("d2h", puts)):
         assert sum(s.meta["bytes"] for s in got) == sum(
             t.wire_bytes for t in traced["transfers"] if t.direction == op)
+
+
+def test_each_early_put_has_its_copy_started_ahead(traced):
+    """Every put that reports ``early`` collects a copy started in an
+    earlier visit: one ``ooc.store.d2h.start`` span per such put, of
+    the device bytes copied (no fewer than the wire's, which count an
+    exponent as 16 bits), the starts leading the puts."""
+    puts = [s for s in _named(traced, spans.PUT) if s.meta["early"]]
+    starts = _named(traced, spans.D2H_START)
+    assert len(starts) == len(puts)
+    assert sum(s.meta["bytes"] for s in starts) >= sum(
+        p.meta["bytes"] for p in puts)
+    if puts:
+        assert min(s.start for s in starts) < min(p.start for p in puts)
+        assert max(s.end for s in starts) < max(p.start for p in puts)
 
 
 def test_digests_per_crossing(traced):
@@ -210,8 +227,8 @@ def test_metadata(traced):
         spans.STENCIL: {"block"},
         spans.ENCODE: {"block"},
         spans.STAGE: {"field", "unit", "bytes"},
-        spans.PUT: {"field", "unit", "bytes", "op"},
-        spans.CHECKSUM: {"bytes"},
+        spans.PUT: {"field", "unit", "bytes", "op", "early"},
+        spans.CHECKSUM: {"bytes"}, spans.D2H_START: {"bytes"},
         spans.WAIT: set(), spans.D2H: set(), spans.H2D: set(),
         spans.FINISH: set(), spans.FLUSH: set(),
     }
@@ -221,6 +238,9 @@ def test_metadata(traced):
     for s in _named(traced, spans.PUT):
         assert s.meta["op"] == "d2h"
         assert s.meta["field"] in ("p_prev", "p_cur")
+        # streamed, every put is a drain's, its copy started at park;
+        # resident, every put is a flush's, of a value never started
+        assert bool(s.meta["early"]) == (traced["mode"] == "streamed")
 
 
 def test_a_drain_retires_an_earlier_visit(traced):
